@@ -5,14 +5,32 @@ Fake host (CPU) devices for SPMD demos/tests are created via
 before the JAX backend initializes. This module therefore imports JAX
 only *inside* the function, so ``from repro.api import
 ensure_host_devices`` stays safe at the very top of a script.
+
+The bootstrap also places JAX's persistent compilation cache, so a
+repeated run of any entry point reads its compiled programs back: where
+``$JAX_COMPILATION_CACHE_DIR`` is set, JAX uses it and nothing here
+overrides it; otherwise the cache lives at a fixed path inside the
+checkout, ``<checkout>/.jax_cache`` (a temporary or per-run directory
+would never be found again by the next run).
 """
 
 from __future__ import annotations
 
 import os
+import pathlib
 import re
 
 _FLAG = "--xla_force_host_platform_device_count"
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def _place_compile_cache() -> None:
+    import jax
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            or jax.config.jax_compilation_cache_dir:
+        return  # placed from outside: leave it alone
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
 
 
 def ensure_host_devices(n: int | None = None, *, default: int = 8,
@@ -44,6 +62,7 @@ def ensure_host_devices(n: int | None = None, *, default: int = 8,
 
     import jax
 
+    _place_compile_cache()
     devices = jax.devices()
     if devices and devices[0].platform != "cpu":
         # real accelerators: the fake-host-device flag does not apply —

@@ -161,7 +161,7 @@ class Session:
             # confining FSDP to the NVLink island), so judge the derived
             # layout, not the topology's nominal pods field
             return self._topology_layout()["pods"] > 1
-        return self.spec.multi_pod or self.spec.pods is not None
+        return self.spec.pods is not None
 
     @property
     def pods_size(self) -> int:
@@ -192,29 +192,26 @@ class Session:
             if self.spec.devices is not None:
                 from repro.api.devices import ensure_host_devices
                 ensure_host_devices(self.spec.devices)
-            if not self.spec.reduced:
-                from repro.launch.mesh import make_production_mesh
-                self._mesh = make_production_mesh(
-                    multi_pod=self.spec.multi_pod)
+            # the devices JAX has: one chip, a four-chip host, or the
+            # fake CPU devices of a test (production-scale meshes come
+            # from a topology preset, as in the dry-run)
+            n_dev = jax.device_count()
+            model = self.geo.model_ranks
+            pods = self.spec.pods or 1
+            data = self._data or max(1, n_dev // (pods * model))
+            self._data = data
+            need = pods * data * model
+            if need > n_dev:
+                raise SessionError(
+                    f"mesh ({'pods×' if pods > 1 else ''}data×model = "
+                    f"{need}) exceeds the {n_dev} available devices; "
+                    f"call repro.api.ensure_host_devices({need}) "
+                    f"before any other JAX use, or shrink data=/pods=")
+            if pods > 1:
+                self._mesh = jax.make_mesh(
+                    (pods, data, model), ("pod", "data", "model"))
             else:
-                n_dev = jax.device_count()
-                model = self.geo.model_ranks
-                pods = self.spec.pods or 1
-                data = self._data or max(1, n_dev // (pods * model))
-                self._data = data
-                need = pods * data * model
-                if need > n_dev:
-                    raise SessionError(
-                        f"mesh ({'pods×' if pods > 1 else ''}data×model = "
-                        f"{need}) exceeds the {n_dev} available devices; "
-                        f"call repro.api.ensure_host_devices({need}) "
-                        f"before any other JAX use, or shrink data=/pods=")
-                if pods > 1:
-                    self._mesh = jax.make_mesh(
-                        (pods, data, model), ("pod", "data", "model"))
-                else:
-                    self._mesh = jax.make_mesh((data, model),
-                                               ("data", "model"))
+                self._mesh = jax.make_mesh((data, model), ("data", "model"))
         return self._mesh
 
     @property
@@ -870,7 +867,7 @@ class Session:
         :meth:`adopt_params` to carry params across."""
         spec = dataclasses.replace(
             self.spec, topology=topology, data=None, pods=None,
-            multi_pod=False, devices=None, mesh=None)
+            devices=None, mesh=None)
         return Session(spec)
 
     def lower(self):

@@ -37,7 +37,11 @@ class SessionSpec:
     # named ShapeConfig ("train_4k", ...), an explicit ShapeConfig, or
     # None to derive one from seq_len / global_batch / the RunConfig.
     shape: str | ShapeConfig | None = None
-    reduced: bool = True            # reduced() smoke config vs production
+    # True: the arch's tiny reduced() smoke config. False: published
+    # widths — the arch's chip_share() cut (one chip's share of a stated
+    # deployment) on the attached devices, or in mode="dry-run" the full
+    # config() with production_run(shape) on a topology preset.
+    reduced: bool = True
     # pipeline schedule: a registered name, "auto" to run the §4
     # selection (every registered schedule + the autogen heuristic,
     # simulated under `cost_preset`; minimum makespan wins), or
@@ -75,7 +79,6 @@ class SessionSpec:
     optim: Mapping[str, Any] = dataclasses.field(default_factory=dict)
     data: int | None = None         # data-axis size (None -> derived)
     pods: int | None = None         # hybrid-sharded DP axis (reduced runs)
-    multi_pod: bool = False         # production 2-pod mesh (dry-run)
     devices: int | None = None      # ensure this many host devices first
     seq_len: int | None = None      # derived-shape sequence length
     global_batch: int | None = None  # derived-shape global batch
@@ -101,7 +104,7 @@ class SessionSpec:
     mesh: Any = None                # pre-built jax Mesh (advanced)
     # hardware topology: a preset name ("fake_cpu", "gpu_cluster",
     # "tpu_pod", "tpu_pod_x2"), a repro.runtime.topology.Topology, or a
-    # kwargs dict. Subsumes the data=/pods=/multi_pod=/devices=/mesh=
+    # kwargs dict. Subsumes the data=/pods=/devices=/mesh=
     # knobs: the DP×FSDP×PP axis layout is derived from the hardware
     # under cost_preset and Session.mesh is built from it.
     topology: Any = None
@@ -229,7 +232,6 @@ class SessionSpec:
         if self.topology is not None:
             clash = [k for k, v in (("data", self.data),
                                     ("pods", self.pods),
-                                    ("multi_pod", self.multi_pod or None),
                                     ("devices", self.devices),
                                     ("mesh", self.mesh)) if v is not None]
             if clash:
@@ -267,9 +269,10 @@ class SessionSpec:
             raise SessionError(
                 f"unknown shape {self.shape!r}; named shapes: "
                 f"{', '.join(sorted(SHAPES))} (or pass a ShapeConfig)")
-        if not self.reduced and not isinstance(self.shape, str):
+        if not self.reduced and self.mode == "dry-run" \
+                and not isinstance(self.shape, str):
             raise SessionError(
-                "production sessions (reduced=False) need a named shape "
+                "production dry-runs (reduced=False) need a named shape "
                 f"from {sorted(SHAPES)} so production_run(shape) can pick "
                 "the RunConfig")
         if self.shape is None and self.mode == "serve" \
@@ -374,9 +377,17 @@ class SessionSpec:
                     f"architecture {self.arch!r} has no reduced() config; "
                     "pass reduced=False with a named shape")
             cfg, rc = mod.reduced()
-        else:
+        elif self.mode == "dry-run":
             cfg = mod.config()
             rc = mod.production_run(self.shape)
+        else:
+            if not hasattr(mod, "chip_share"):
+                raise SessionError(
+                    f"architecture {self.arch!r} has no chip_share() cut "
+                    "(published widths sized to one chip's share of a "
+                    "deployment); run it reduced, or compile it at full "
+                    "size with mode='dry-run'")
+            cfg, rc = mod.chip_share()
         if self.overrides:
             rc = dataclasses.replace(rc, **self.overrides)
         return mod, cfg, rc

@@ -1,7 +1,8 @@
 """The paper's GPT models (Table 4): 1.5B / 6.2B / 14.6B, seq 1024.
 
 Used by the Table-3/5 and Fig-5/6/7 benchmark reproductions (simulator cost
-model) and by the end-to-end training example at reduced width.
+model), by the end-to-end training example at reduced width, and — through
+``chip_share()`` — at the 1.5B model's published widths on one chip.
 """
 
 from repro.models.common import ModelConfig, RunConfig
@@ -42,3 +43,33 @@ def reduced():
     rc = RunConfig(pp=2, vpp=2, microbatches=2, param_dtype="float32",
                    compute_dtype="float32")
     return cfg, rc
+
+
+# One chip's share of the paper's 1.5B deployment (``paper_run``: PP=4 ×
+# FSDP=4). Each pipeline stage holds ceil(22 / 4) = 6 layers; this chip
+# holds one stage's layers whole plus the untied embedding and head (in
+# the deployment they sit on the first and last stage). No width is cut.
+CHIP_SHARE = {
+    "source": "ZeroPP (arXiv 2402.03791) Table 4, GPT 1.5B: 22 layers, "
+              "24 heads, d_model 2304, seq 1024",
+    "deployment": "PP=4 x FSDP=4; one stage's layers on this chip, "
+                  "embedding and head both held here",
+    "reduced": {"n_layers": {"published": 22, "here": 6}},
+    "assumed": {"vocab": "50304 (GPT-2's 50257 padded to a multiple of 64)",
+                "d_ff": "4 x d_model", "d_head": "d_model / n_heads = 96"},
+}
+
+
+def chip_share() -> tuple[ModelConfig, RunConfig]:
+    """1.5B at published widths, cut to one stage (see ``CHIP_SHARE``):
+    ≈614M parameters, ≈9.8 GB of bf16 weights + f32 grads and Adam state
+    at 16 B/param, leaving the rest of a 16 GB v5e for activations at seq
+    1024 over 8 micro-batches (the paper's ``n_micro``). pp=1: the whole
+    stage runs on one device; a four-chip host overrides pp/data."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        config("1.5B"), name="gpt-1.5B-stage",
+        n_layers=CHIP_SHARE["reduced"]["n_layers"]["here"])
+    return cfg, RunConfig(pp=1, vpp=1, microbatches=8, unit=1,
+                          schedule="zeropp")

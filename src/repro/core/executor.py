@@ -302,6 +302,10 @@ class TickEngine:
         it: reduce-scatter is linear and every contribution passes
         through exactly one scatter, so the per-shard sum is unchanged.
         """
+        if self.dsize == 1:
+            # one-wide data axis: grads accumulate straight into
+            # acc_shard (no acc_full) and there is nothing to scatter
+            return c
         rv = row["reduce_v"]
         rs_dt = jnp.dtype(self.rs_dtype)
         flat_set = set(self.gatherable) if self.flat is not None else set()
@@ -445,6 +449,10 @@ def segment_train_scan(
 
     rope = _rope_for(cfg, rc, seq)
     dsize = rt.dsize
+    # where B/W accumulate full-size grads: the per-unit buffer that the
+    # reduce tick scatters, or — on a one-wide data axis, where the
+    # scatter is the identity — the returned shard accumulator itself
+    acc_key = "acc_full" if dsize > 1 else "acc_shard"
 
     flat = rt.flat_layouts.get(seg.name)
     eng = TickEngine(
@@ -473,7 +481,8 @@ def segment_train_scan(
             wdy=[jnp.zeros((V, U, *sh), dt) for sh, dt in tmpl.dy_shapes],
             gbuf=eng.init_gbuf(),
             acc_full={n: jnp.zeros((V, *specs[n].shape), jnp.float32)
-                      for n in specs if n not in ep_names},
+                      for n in specs
+                      if n not in ep_names and acc_key == "acc_full"},
             **({"gerr": gerr0} if gerr0 is not None else {}),
             acc_shard={n: jnp.zeros(
                 (V, *_local_shape(specs[n], dsize, rt.ep)), jnp.float32)
@@ -676,9 +685,9 @@ def segment_train_scan(
                 c["acc_shard"][n] = _dyn_add(c["acc_shard"][n], v,
                                              g.astype(jnp.float32))
             else:
-                c["acc_full"] = dict(c["acc_full"])
-                c["acc_full"][n] = _dyn_add(c["acc_full"][n], v,
-                                            g.astype(jnp.float32))
+                c[acc_key] = dict(c[acc_key])
+                c[acc_key][n] = _dyn_add(c[acc_key][n], v,
+                                         g.astype(jnp.float32))
 
         # embedding gradient at the first stage
         if int_tokens:
@@ -730,7 +739,7 @@ def segment_train_scan(
             xv = _dyn_get2(c["wx"][xs_i], v, uu)
             dyv = _dyn_get2(c["wdy"][dy_i], v, uu)
             g = jnp.einsum(spec_s, xv, dyv).astype(jnp.float32)
-            c["acc_full"][pname] = _dyn_add(c["acc_full"][pname], v, g)
+            c[acc_key][pname] = _dyn_add(c[acc_key][pname], v, g)
         return c
 
     def nop_branch(c, row):

@@ -57,21 +57,6 @@ from repro.models.common import FlatEntry, FlatLayout, ParamSpec
 DATA, MODEL, POD = "data", "model", "pod"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across JAX versions.
-
-    Older JAX (< 0.6) ships it as ``jax.experimental.shard_map`` with the
-    replication check named ``check_rep`` instead of ``check_vma``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 # --------------------------------------------------------------------------- #
 # PartitionSpecs
 # --------------------------------------------------------------------------- #

@@ -210,10 +210,13 @@ class Runtime:
                 for sname, sps in self.stage_specs.items()
             },
         }
+        # a one-wide data axis has nothing to gather: stage params stay
+        # resident (read in place, no two-slot gathered copy)
         self.gatherable = {
             sname: sorted(
                 n for n, sp_ in sps.items()
-                if fsdp.local_dim(sp_, self.dsize, self.ep) is not None
+                if self.dsize > 1
+                and fsdp.local_dim(sp_, self.dsize, self.ep) is not None
                 and not (sp_.ep and self.ep)
             )
             for sname, sps in self.stage_specs.items()
@@ -450,7 +453,7 @@ def make_train_step(rt: Runtime, shape_cfg):
         grad_specs = {"io": rt.pspecs["io"],
                       "segments": rt.pspecs["segments"]}
         out_specs = (grad_specs, P())
-        fn = fsdp.shard_map(
+        fn = jax.shard_map(
             partial(_train_body, rt=rt, shape_cfg=shape_cfg, mbs=mbs,
                     vloc=vloc, denom=denom, aux_seed=aux_seed),
             mesh=mesh, in_specs=in_specs, out_specs=out_specs,
@@ -731,7 +734,7 @@ def make_serve_step(rt: Runtime, shape_cfg, *, prompt_len: int = 1,
             out_specs = (tok_spec, logit_spec, in_specs[1]) + moe_spec
         else:
             out_specs = (tok_spec, in_specs[1]) + moe_spec
-        fn = fsdp.shard_map(
+        fn = jax.shard_map(
             partial(_serve_body, rt=rt, shape_cfg=shape_cfg, mbs=mbs,
                     Btot=Btot, vloc=vloc, prompt_len=prompt_len,
                     max_seq=max_seq, seq_shard=seq_shard,

@@ -22,6 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 DATA = "data"
 
 
@@ -150,15 +152,13 @@ def loss_and_dy(cfg, rc, io_p, h, labels, denom: float, vloc: int | None,
 
     if vloc is None:
         wl = (w.T if tied else w).astype(jnp.float32)  # [d, vocab]
-        logits = hn @ wl
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        lab = jnp.take_along_axis(logits, labels[:, None], 1)[:, 0]
-        loss = ((lse - lab) * mask).sum() / denom
-        p = jnp.exp(logits - lse[:, None])
-        dlog = (p - jax.nn.one_hot(labels, wl.shape[1])) \
-            * mask[:, None] / denom
-        dhn = dlog @ wl.T
-        dw = hn.T @ dlog
+        loss, (dhn, dw) = ops.softmax_xent(
+            hn, wl, labels, chunk=rc.vocab_chunk, mask=mask,
+            impl=rc.kernel_impl)
+        # softmax_xent normalizes by the mask's count; this loss (and its
+        # gradients) by the global token count
+        rescale = jnp.maximum(mask.sum(), 1.0) / denom
+        loss, dhn, dw = loss * rescale, dhn * rescale, dw * rescale
         dh, ng = _final_norm_bwd(cfg, res, dhn, norm_key)
         grads = dict(ng)
         key = "embed.table" if tied else "head.w"

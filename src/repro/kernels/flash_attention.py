@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ops, ref
+
 NEG_INF = -1e30
 
 
@@ -74,11 +76,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         ).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "q_offset", "block_q", "block_k",
-                     "interpret"),
-)
 def flash_attention(q, k, v, *, causal=True, q_offset=0, block_q=128,
                     block_k=128, interpret=False):
     """q: [b, sq, h, e]; k: [b, sk, g, e]; v: [b, sk, g, ev] → [b, sq, h, ev].
@@ -86,7 +83,43 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, block_q=128,
     q_offset shifts absolute q positions (decode windows); the kernel
     assumes q_offset == 0 for the causal mask when sq == sk (training) —
     decode uses ops.decode_attention instead.
+
+    Differentiable through a ``custom_vjp`` whose backward is the vjp of
+    the jnp reference (``ref.attention``): the forward runs the Pallas
+    kernel, the backward recomputes attention in XLA from the saved
+    q/k/v. Each traced backward bumps the ``ref_attention_bwd`` kernel
+    counter, so the reference backward is visible in
+    ``Session.describe()["kernels"]``.
     """
+    return _flash_vjp(q, k, v, causal, q_offset, block_q, block_k,
+                      interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_vjp(q, k, v, causal, q_offset, block_q, block_k, interpret):
+    return _flash_fwd(q, k, v, causal=causal, block_q=block_q,
+                      block_k=block_k, interpret=interpret)
+
+
+def _flash_vjp_fwd(q, k, v, causal, q_offset, block_q, block_k, interpret):
+    out = _flash_vjp(q, k, v, causal, q_offset, block_q, block_k, interpret)
+    return out, (q, k, v)
+
+
+def _flash_vjp_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
+    ops._count("ref_attention_bwd")
+    _, vjp = jax.vjp(
+        functools.partial(ref.attention, causal=causal, q_offset=q_offset),
+        *res)
+    return vjp(g)
+
+
+_flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
+def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     b, sq, h, e = q.shape
     _, sk, g, ev = v.shape
     rep = h // g

@@ -1,8 +1,8 @@
 """Dispatch layer: Pallas TPU kernels on TPU, jnp references elsewhere.
 
 All model code calls through these functions. The choice is made per-call
-from (a) the default backend, (b) the ``REPRO_FORCE_REF`` env var, and
-(c) an explicit ``impl=`` override — so tests can compare both paths.
+from (a) the default backend and (b) an explicit ``impl=`` override — so
+tests can compare both paths.
 
 Every dispatch decision bumps a trace-time counter (``kernel_counters``):
 one count per *traced call site*, not per executed step, since dispatch
@@ -13,13 +13,9 @@ was selected but the shape/backend combination still forced the ref path.
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 from repro.kernels import ref
-
-_FORCE_REF = os.environ.get("REPRO_FORCE_REF", "0") == "1"
 
 # Trace-time dispatch counters, keyed by implementation event. Monotonic
 # process-wide; consumers snapshot and diff (see Session.describe()).
@@ -36,10 +32,7 @@ def kernel_counters() -> dict[str, int]:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _use_pallas(impl: str | None) -> bool:
@@ -47,7 +40,7 @@ def _use_pallas(impl: str | None) -> bool:
         return True
     if impl == "ref":
         return False
-    return _on_tpu() and not _FORCE_REF
+    return _on_tpu()
 
 
 def _interpret() -> bool:
@@ -136,12 +129,17 @@ def combine_decode_shards(partials):
 
 def selective_scan(x, dt, A, B, C, D, *, chunk=256, h0=None,
                    return_state=False, impl=None):
-    if _use_pallas(impl):
+    if not _use_pallas(impl):
+        _count("ref_selective_scan")
+    elif h0 is not None or return_state:
+        # the kernel has no state in/out (training only): counted fallback
+        _count("fallback_selective_scan_ref")
+    else:
+        _count("pallas_selective_scan")
         from repro.kernels import selective_scan as ss
 
-        return ss.selective_scan(
-            x, dt, A, B, C, D, chunk=chunk, h0=h0, return_state=return_state
-        )
+        return ss.selective_scan(x, dt, A, B, C, D, chunk=chunk,
+                                 interpret=_interpret())
     return ref.selective_scan(
         x, dt, A, B, C, D, chunk=chunk, h0=h0, return_state=return_state
     )
@@ -169,7 +167,10 @@ def slstm_scan(x_gates, *, state=None, return_state=False, impl=None):
 
 def softmax_xent(h, w_head, labels, *, chunk=8192, mask=None, impl=None):
     if _use_pallas(impl):
+        _count("pallas_xent")
         from repro.kernels import fused_xent
 
-        return fused_xent.softmax_xent(h, w_head, labels, mask=mask)
+        return fused_xent.softmax_xent(h, w_head, labels, mask=mask,
+                                       interpret=_interpret())
+    _count("ref_xent")
     return ref.softmax_xent(h, w_head, labels, chunk=chunk, mask=mask)

@@ -92,13 +92,13 @@ def _slotted_kernel(pos_ref, q_ref, k_ref, v_ref, acc_o, m_o, l_o,
         mask = mask & (k_pos <= q_pos)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_sc[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_sc[...]                               # [sq_p, 1]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(mask, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_sc[...] = l_sc[...] * corr + p.sum(axis=1)
-    acc_sc[...] = acc_sc[...] * corr[:, None] + jax.lax.dot_general(
+    l_sc[...] = l_sc[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_sc[...] = m_new
@@ -161,12 +161,12 @@ def flash_attention_slotted(q, k, v, *, pos, window=False,
         ],
         out_specs=[
             pl.BlockSpec((1, sq_p, ev), lambda bh, ik, pr: (bh, 0, 0)),
-            pl.BlockSpec((1, sq_p), lambda bh, ik, pr: (bh, 0)),
-            pl.BlockSpec((1, sq_p), lambda bh, ik, pr: (bh, 0)),
+            pl.BlockSpec((1, sq_p, 1), lambda bh, ik, pr: (bh, 0, 0)),
+            pl.BlockSpec((1, sq_p, 1), lambda bh, ik, pr: (bh, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((sq_p,), jnp.float32),
-            pltpu.VMEM((sq_p,), jnp.float32),
+            pltpu.VMEM((sq_p, 1), jnp.float32),
+            pltpu.VMEM((sq_p, 1), jnp.float32),
             pltpu.VMEM((sq_p, ev), jnp.float32),
         ],
     )
@@ -175,13 +175,13 @@ def flash_attention_slotted(q, k, v, *, pos, window=False,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq_p, ev), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, sq_p), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32),
         ],
         interpret=interpret,
     )(pos, qr, kr, vr)
 
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    out = acc / jnp.maximum(l, 1e-30)
     out = out.reshape(b, h, sq_p, ev)[:, :, :sq]
     o_bqhe = out.transpose(0, 2, 1, 3).astype(q.dtype)
     if not return_stats:
@@ -212,10 +212,13 @@ def decode_attention(q, k_cache, v_cache, cache_len=None, *, block_k=128,
 # --------------------------------------------------------------------------- #
 
 
-def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, acc_o, m_o, l_o,
-                  m_sc, l_sc, acc_sc, *, h, ps, sq_p, scale, ppr,
-                  ks_ref=None, vs_ref=None):
-    bh = pl.program_id(0)
+def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, h, rep,
+                  ps, sq_p, scale, ppr, quant):
+    if quant:
+        ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc = refs
+    else:
+        o_ref, m_sc, l_sc, acc_sc = refs
+    ib = pl.program_id(0)
     ip = pl.program_id(1)
 
     @pl.when(ip == 0)
@@ -224,41 +227,45 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, acc_o, m_o, l_o,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    q = q_ref[0].astype(jnp.float32) * scale        # [sq_p, e]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)       # [ps, e]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)       # [ps, ev]
-    if ks_ref is not None:
-        k = k * ks_ref[0, 0]
-        v = v * vs_ref[0, 0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # [sq_p, ps]
-
     # logical key positions of this page-table entry; sentinel tail
     # entries sit past the row's causal offset, so the exact causal
     # mask zeroes whatever live page their id 0 happens to alias.
-    row = pos_ref[bh // h]
+    row = pos_ref[ib]
     k_pos = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (sq_p, ps), 1)
     q_pos = row + jax.lax.broadcasted_iota(jnp.int32, (sq_p, ps), 0)
     mask = k_pos <= q_pos
-    s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_sc[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_sc[...] = l_sc[...] * corr + p.sum(axis=1)
-    acc_sc[...] = acc_sc[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_sc[...] = m_new
+    # One grid step holds the whole page [ps, g, e]; each q head reads
+    # its kv head's [ps, e] rows with a static index (a strided sublane
+    # load — a one-head *block* of the page would break Mosaic's tiling).
+    for i in range(h):
+        j = i // rep
+        q = q_ref[0, i].astype(jnp.float32) * scale   # [sq_p, e]
+        k = k_ref[0, :, j, :].astype(jnp.float32)     # [ps, e]
+        v = v_ref[0, :, j, :].astype(jnp.float32)     # [ps, ev]
+        if quant:
+            k = k * ks_ref[0, 0, j]
+            v = v * vs_ref[0, 0, j]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [sq_p, ps]
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_sc[i]                             # [sq_p, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[i] = l_sc[i] * corr + p.sum(axis=1, keepdims=True)
+        acc_sc[i] = acc_sc[i] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[i] = m_new
 
     @pl.when(ip == ppr - 1)
     def _flush():
-        acc_o[0] = acc_sc[...]
-        m_o[0] = m_sc[...]
-        l_o[0] = l_sc[...]
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -272,6 +279,10 @@ def paged_attention(q, k_pool, v_pool, *, page_tables, pos, k_scale=None,
     allowed); pos: [b] int32 first absolute position of each row's q.
     ``slot_mask`` [b] bool: masked-off rows emit zeros (their page
     tables may be stale). Returns [b, sq, h, ev] in q.dtype.
+
+    Grid (row, page-table entry): each step stages one whole page (all
+    kv heads) and updates every q head of the row, so a page is read
+    once per row rather than once per head.
     """
     b, sq, h, e = q.shape
     n_pages, ps, g, ev = v_pool.shape
@@ -290,64 +301,50 @@ def paged_attention(q, k_pool, v_pool, *, page_tables, pos, k_scale=None,
     sq_p = _ceil_to(sq, 8)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
-    qr = q.transpose(0, 2, 1, 3).reshape(b * h, sq_p, e)
+    qr = q.transpose(0, 2, 1, 3)                     # [b, h, sq_p, e]
 
-    kernel = functools.partial(
-        _paged_kernel, h=h, ps=ps, sq_p=sq_p, scale=scale, ppr=ppr)
-    if quant:
-        def kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   acc_o, m_o, l_o, m_sc, l_sc, acc_sc):
-            return _paged_kernel(
-                pt_ref, pos_ref, q_ref, k_ref, v_ref, acc_o, m_o, l_o,
-                m_sc, l_sc, acc_sc, h=h, ps=ps, sq_p=sq_p, scale=scale,
-                ppr=ppr, ks_ref=ks_ref, vs_ref=vs_ref)
+    def page_map(ib, ip, pt_ref, pos_ref):
+        return (pt_ref[ib, ip], 0, 0, 0)
 
-    def page_map(bh, ip, pt_ref, pos_ref, rep=rep):
-        return (pt_ref[bh // h, ip], 0, (bh % h) // rep, 0)
+    def scale_map(ib, ip, pt_ref, pos_ref):
+        return (pt_ref[ib, ip], 0, 0)
 
-    def scale_map(bh, ip, pt_ref, pos_ref, rep=rep):
-        return (pt_ref[bh // h, ip], (bh % h) // rep)
+    def row_map(ib, ip, pt_ref, pos_ref):
+        return (ib, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, sq_p, e), lambda bh, ip, ptr, pr: (bh, 0, 0)),
-        pl.BlockSpec((1, ps, 1, e), page_map),
-        pl.BlockSpec((1, ps, 1, ev), page_map),
+        pl.BlockSpec((1, h, sq_p, e), row_map),
+        pl.BlockSpec((1, ps, g, e), page_map),
+        pl.BlockSpec((1, ps, g, ev), page_map),
     ]
     operands = [qr, k_pool, v_pool]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, 1), scale_map, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), scale_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, g), scale_map, memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, g), scale_map, memory_space=pltpu.SMEM),
         ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # [n_pages, 1, g]: a page's scales are a whole trailing block
+        operands += [k_scale.astype(jnp.float32)[:, None],
+                     v_scale.astype(jnp.float32)[:, None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * h, ppr),
+        grid=(b, ppr),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, sq_p, ev), lambda bh, ip, ptr, pr: (bh, 0, 0)),
-            pl.BlockSpec((1, sq_p), lambda bh, ip, ptr, pr: (bh, 0)),
-            pl.BlockSpec((1, sq_p), lambda bh, ip, ptr, pr: (bh, 0)),
-        ],
+        out_specs=pl.BlockSpec((1, h, sq_p, ev), row_map),
         scratch_shapes=[
-            pltpu.VMEM((sq_p,), jnp.float32),
-            pltpu.VMEM((sq_p,), jnp.float32),
-            pltpu.VMEM((sq_p, ev), jnp.float32),
+            pltpu.VMEM((h, sq_p, 1), jnp.float32),
+            pltpu.VMEM((h, sq_p, 1), jnp.float32),
+            pltpu.VMEM((h, sq_p, ev), jnp.float32),
         ],
     )
-    acc, m, l = pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, h=h, rep=rep, ps=ps, sq_p=sq_p,
+                          scale=scale, ppr=ppr, quant=quant),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, ev), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, sq_p), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, sq_p), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, ev), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
     )(pt, pos, *operands)
-
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = out.reshape(b, h, sq_p, ev)[:, :, :sq]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    return out[:, :, :sq].transpose(0, 2, 1, 3)

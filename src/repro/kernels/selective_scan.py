@@ -54,12 +54,10 @@ def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, h_sc, *,
 
 @functools.partial(
     jax.jit, static_argnames=("chunk", "block_d", "interpret"))
-def selective_scan(x, dt, A, B, C, D, *, chunk=128, block_d=256, h0=None,
-                   return_state=False, interpret=False):
-    """Same contract as ref.selective_scan (h0/return_state unsupported in
-    the kernel path — ops.py falls back to the reference for those)."""
-    assert h0 is None and not return_state, (
-        "kernel path serves training; stateful decode uses the reference")
+def selective_scan(x, dt, A, B, C, D, *, chunk=128, block_d=256,
+                   interpret=False):
+    """Same contract as ref.selective_scan without state in/out (training
+    only — ops.py routes stateful prefill to the reference, counted)."""
     b, s, d = x.shape
     n = A.shape[1]
     pc = -s % chunk

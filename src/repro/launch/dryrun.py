@@ -84,7 +84,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str | None):
             unit=min(rc0.unit or 10**9, max(per_dp // rc0.groups, 1)))
     t0 = time.time()
     sess = session(arch, mode="dry-run", shape=shape, reduced=False,
-                   multi_pod=multi_pod, overrides=overrides)
+                   topology="tpu_pod_x2" if multi_pod else "tpu_pod",
+                   overrides=overrides)
     lowered = sess.lower()
     t_lower = time.time() - t0
     rt = sess.rt  # roofline analysis reads the runtime's static tables

@@ -1,12 +1,9 @@
-"""Production meshes — thin wrapper over the topology presets.
+"""TPU v5e hardware constants, re-exported from the topology layer.
 
-The 16×16 pod shape and TPU v5e constants that used to be hard-coded
-here live in :mod:`repro.runtime.topology` now; this module stays
-importable (benchmarks/roofline.py pulls the constants) and keeps the
-historical ``make_production_mesh`` entry point. Kept as FUNCTIONS so
-importing this module never touches jax device state (entry points call
-repro.api.ensure_host_devices() before any other JAX use; tests use
-their own small meshes in subprocesses).
+The 16×16 pod shape and the constants that used to be hard-coded here
+live in :mod:`repro.runtime.topology` now (the dry-run builds its pod
+meshes from the ``tpu_pod`` / ``tpu_pod_x2`` presets); this module stays
+importable because benchmarks/roofline.py pulls the constants.
 """
 
 from __future__ import annotations
@@ -17,14 +14,3 @@ from repro.runtime.topology import (  # noqa: F401  (re-exports)
     PEAK_FLOPS_BF16,
     TOPOLOGY_PRESETS,
 )
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod.
-
-    Axes: "data" = FSDP (+EP +vocab) axis, "model" = pipeline-group ×
-    pipeline-stage axis (TP-free per the paper), "pod" = hybrid-sharded DP
-    (params replicated, grads all-reduced once per step).
-    """
-    preset = TOPOLOGY_PRESETS["tpu_pod_x2" if multi_pod else "tpu_pod"]
-    return preset.build_mesh(16, cost_preset="tpu_v5e")

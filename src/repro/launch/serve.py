@@ -27,7 +27,7 @@ import argparse
 import json
 import time
 
-from repro.api import ensure_host_devices, get_arch, session
+from repro.api import SessionSpec, ensure_host_devices, session
 
 
 def load_requests(path: str, vocab: int, seed: int = 0):
@@ -92,7 +92,14 @@ def main():
                     help="mean synthetic generation budget")
     ap.add_argument("--requests", default=None,
                     help="workload file (JSON array or JSON-lines)")
-    ap.add_argument("--data", type=int, default=2)
+    ap.add_argument("--data", type=int, default=None,
+                    help="FSDP axis width (default: what the devices "
+                         "give after the pipeline axis)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the arch's tiny reduced() config; --no-reduced "
+                         "serves its published widths cut to one chip's "
+                         "share")
     ap.add_argument("--max-seq", type=int, default=None,
                     help="KV cache length (default: fits the workload)")
     ap.add_argument("--prefill-chunk", type=int, default=None,
@@ -152,8 +159,10 @@ def main():
     import jax
 
     # size the workload before the session so max_seq can default to
-    # whatever the requests actually need (sessions default to reduced())
-    vocab = get_arch(args.arch).reduced()[0].vocab
+    # whatever the requests actually need; ids come from the vocab of the
+    # config the session will resolve (reduced or published widths)
+    vocab = SessionSpec(args.arch, reduced=args.reduced).resolve_configs(
+    )[1].vocab
     if args.requests:
         work = load_requests(args.requests, vocab)
     else:
@@ -170,7 +179,8 @@ def main():
         max_seq = -(-max_seq // args.page_size) * args.page_size
 
     sess_kw = dict(
-        mode="serve", data=args.data, max_slots=args.slots,
+        mode="serve", reduced=args.reduced, data=args.data,
+        max_slots=args.slots,
         max_seq=max_seq, schedule=args.schedule, cost_preset=args.preset,
         prefill_chunk=args.prefill_chunk, page_size=args.page_size,
         max_pages=args.max_pages, prefix_sharing=args.prefix_sharing,

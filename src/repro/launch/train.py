@@ -9,6 +9,12 @@ the winner.
 Usage (CPU demo; device count via SPMD_DEVICES, default 8):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b \
       --steps 100 --data 2 [--schedule auto] [--ckpt-dir /tmp/ckpt]
+
+On a TPU host the topology is the attached one (its chips, as JAX sees
+them); ``--no-reduced`` trains the arch's published widths cut to one
+chip's share (``chip_share()`` in its config), e.g.
+  python -m repro.launch.train --arch gpt_paper --no-reduced --seq 1024 \
+      --microbatches 8 --unit 1 --steps 5 --ckpt-dir <fresh dir>
 """
 
 from __future__ import annotations
@@ -20,7 +26,7 @@ from repro.api import ensure_host_devices, session
 
 def build_session(arch: str, *, data: int | None = None, seq: int,
                   microbatches: int, schedule: str, lr: float,
-                  unit: int = 0, preset: str = "a800",
+                  unit: int = 0, preset: str = "a800", reduced: bool = True,
                   profile_top_k: int = 3,
                   profile_budget_s: float | None = None,
                   moe_mode: str | None = None, moe_stats: bool = False,
@@ -49,7 +55,7 @@ def build_session(arch: str, *, data: int | None = None, seq: int,
         ov["moe_stats"] = True
     sess = session(
         arch, mode="train", seq_len=seq, cost_preset=preset,
-        overrides=ov,
+        reduced=reduced, overrides=ov,
         optim=dict(lr=lr, warmup=20, total=10_000), **kw,
     )
     sched = sess.describe()["schedule"]
@@ -102,7 +108,14 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--data", type=int, default=2)
+    ap.add_argument("--data", type=int, default=None,
+                    help="FSDP axis width (default: what the devices "
+                         "give after the pipeline axis)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the arch's tiny reduced() config; --no-reduced "
+                         "trains its published widths cut to one chip's "
+                         "share")
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--microbatches", type=int, default=4)
     ap.add_argument("--unit", type=int, default=0)
@@ -132,8 +145,9 @@ def main():
     ap.add_argument("--inject-failure-at", type=int, default=None)
     ap.add_argument("--topology", default=None,
                     help="hardware topology preset (fake_cpu | "
-                         "gpu_cluster | tpu_pod | tpu_pod_x2); default "
-                         "builds a fake_cpu topology pinned to --data")
+                         "gpu_cluster | tpu_pod | tpu_pod_x2); default: "
+                         "the attached host (its chips, or the fake CPU "
+                         "devices), data axis pinned by --data if given")
     ap.add_argument("--max-failures", type=int, default=3)
     args = ap.parse_args()
 
@@ -147,7 +161,7 @@ def main():
         FaultToleranceConfig,
         TrainController,
     )
-    from repro.runtime.topology import resolve_topology
+    from repro.runtime.topology import attached_topology, resolve_topology
 
     ctl = TrainController(args.ckpt_dir,
                           FaultToleranceConfig(
@@ -159,18 +173,19 @@ def main():
         # fresh session per (re)start: an elastic restart rebuilds on a
         # topology whose data axis halves per failure (node-loss model:
         # the survivors re-mesh; params relayout from the checkpoint)
-        topo = resolve_topology(args.topology or "fake_cpu")
+        topo = (resolve_topology(args.topology) if args.topology
+                else attached_topology())
         if ctl.failures:
             d0 = box["data"]
             topo = _dc.replace(topo, name=None,
                                data=max(1, d0 // (2 ** ctl.failures)))
-        elif args.topology is None:
+        elif args.data is not None:
             topo = _dc.replace(topo, data=args.data)
         sess = build_session(
             args.arch, seq=args.seq,
             microbatches=args.microbatches, schedule=args.schedule,
             lr=args.lr, unit=args.unit, preset=args.preset,
-            profile_top_k=args.profile_top_k,
+            reduced=args.reduced, profile_top_k=args.profile_top_k,
             profile_budget_s=args.profile_budget_s,
             moe_mode=args.moe_mode, moe_stats=args.moe_stats,
             topology=topo, global_batch=box.get("gb"))

@@ -19,6 +19,7 @@ exactly its V stages.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Any
 
 import jax
@@ -441,7 +442,11 @@ def init_all_params(cfg: ModelConfig, rc: RunConfig, key=None):
     for seg in geo.segments:
         sp = stage_specs(cfg, seg)
         S = geo.seg_stages(seg)
-        keys = jax.random.split(jax.random.fold_in(kseg, hash(seg.name) % 2**31), S)
+        # crc32, not hash(): str hashes change with PYTHONHASHSEED, so
+        # the same key would give other weights in another process
+        keys = jax.random.split(
+            jax.random.fold_in(kseg, zlib.crc32(seg.name.encode()) % 2**31),
+            S)
         stacked = None
         per_stage = [init_params(keys[s], sp, dtype) for s in range(S)]
         stacked = {

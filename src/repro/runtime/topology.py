@@ -8,7 +8,9 @@ physical devices:
 
 * a :class:`Topology` describes the hardware — hosts × devices-per-host,
   an interconnect class, and ``kind`` ("fake_cpu" single-process CPU
-  demos, "gpu_cluster" NVLink-island clusters, "tpu_pod" ICI pods);
+  demos, "tpu_host" the chips of one attached TPU host, "gpu_cluster"
+  NVLink-island clusters, "tpu_pod" ICI pods); :func:`attached_topology`
+  reads the live host's kind, chip count and ``device_kind`` from JAX;
 * :meth:`Topology.axis_layout` derives the pods×data×model widths from
   the hardware under a ``cost_preset``: the a800 preset confines the
   FSDP axis to the NVLink island (intra-host gathers) and folds the
@@ -33,12 +35,13 @@ import dataclasses
 import os
 from typing import Any
 
-KINDS = ("fake_cpu", "gpu_cluster", "tpu_pod")
+KINDS = ("fake_cpu", "tpu_host", "gpu_cluster", "tpu_pod")
 
 # default interconnect class per kind (informational + used by the
 # layout derivation notes; the α–β constants live in core/plan.py)
 _INTERCONNECT = {
     "fake_cpu": "host",
+    "tpu_host": "ici",
     "gpu_cluster": "nvlink+ib",
     "tpu_pod": "ici",
 }
@@ -72,6 +75,7 @@ class Topology:
     interconnect: str | None = None
     data: int | None = None         # explicit FSDP-axis width
     name: str | None = None         # preset provenance (None = ad hoc)
+    device_kind: str | None = None  # jax device_kind of a live host
 
     def __post_init__(self):
         if self.interconnect is None:
@@ -275,6 +279,7 @@ class Topology:
             "pods": self.pods,
             "interconnect": self.interconnect,
             "total_devices": self.total_devices,
+            "device_kind": self.device_kind,
         }
         if model_ranks is not None:
             try:
@@ -301,6 +306,30 @@ TOPOLOGY_PRESETS: dict[str, Topology] = {
     "tpu_pod_x2": Topology(kind="tpu_pod", hosts=128, devices_per_host=4,
                            pods=2, name="tpu_pod_x2"),
 }
+
+
+def attached_topology() -> Topology:
+    """The host JAX is attached to: its platform, chip count and
+    ``device_kind`` as ``jax.devices()`` reports them.
+
+    A TPU host is a "tpu_host" topology over its local chips; a CPU
+    backend is "fake_cpu" over the devices it was started with (call
+    :func:`repro.api.ensure_host_devices` first). Any other platform is
+    refused rather than described as something it is not.
+    """
+    import jax
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    kinds = {"cpu": "fake_cpu", "tpu": "tpu_host"}
+    if platform not in kinds:
+        raise TopologyError(
+            f"no attached-host topology for platform {platform!r}; pass a "
+            f"preset ({', '.join(sorted(TOPOLOGY_PRESETS))}) or a Topology")
+    return Topology(kind=kinds[platform], hosts=jax.process_count(),
+                    devices_per_host=jax.local_device_count(),
+                    name="attached",
+                    device_kind=devices[0].device_kind).validate()
 
 
 def resolve_topology(t: Any) -> Topology | None:

@@ -368,11 +368,10 @@ def case_int8_grads(arch: str = "llama3.2-1b"):
         q, err = F.reduce_scatter_grad_int8(gl[0], err0, spec, 4, False)
         return full, q, err
 
-    from repro.core import fsdp as _fsdp
-    f = _fsdp.shard_map(body, mesh=mesh,
-                        in_specs=(P(None, "data"),),
-                        out_specs=(P("data"), P("data"), P(None, "data")),
-                        check_vma=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(None, "data"),),
+                      out_specs=(P("data"), P("data"), P(None, "data")),
+                      check_vma=False)
     # feed each data rank a *different* gradient contribution
     gs = g.transpose(1, 0, 2).reshape(1, 32, 4 * 16)[..., :16 * 4]
     full, q, err = jax.jit(f)(g.sum(0)[None].repeat(4, 0).reshape(
@@ -1236,8 +1235,8 @@ def case_flat_fallback(arch: str = "llama3.2-1b"):
         return ref, got
 
     out_specs = ({n: P() for n in gatherable}, {n: P() for n in gatherable})
-    fg = F.shard_map(body_gather, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
+    fg = jax.shard_map(body_gather, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     ref, got = jax.jit(fg)(full)
     for n in gatherable:
         assert np.array_equal(np.asarray(ref[n]), np.asarray(got[n])), n
@@ -1266,8 +1265,8 @@ def case_flat_fallback(arch: str = "llama3.2-1b"):
 
     out_specs_r = ({n: red_spec(n) for n in specs},
                    {n: red_spec(n) for n in specs})
-    fr = F.shard_map(body_reduce, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs_r, check_vma=False)
+    fr = jax.shard_map(body_reduce, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs_r, check_vma=False)
     ref_r, got_r = jax.jit(fr)(full)
     for n in specs:
         assert np.array_equal(np.asarray(ref_r[n]),

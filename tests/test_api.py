@@ -122,7 +122,10 @@ def test_spec_rejects_bad_mode_and_shape():
     with pytest.raises(SessionError, match="named shapes"):
         session("llama3.2-1b", shape="train_9000k")
     with pytest.raises(SessionError, match="reduced=False"):
-        session("llama3.2-1b", reduced=False)  # needs a named shape
+        # a production dry-run needs a named shape
+        session("llama3.2-1b", mode="dry-run", reduced=False)
+    with pytest.raises(SessionError, match="chip_share"):
+        session("llama3.2-1b", reduced=False)  # no one-chip cut
     with pytest.raises(SessionError, match="max_seq"):
         session("llama3.2-1b", mode="serve")
 
@@ -433,3 +436,56 @@ def test_topology_production_mesh_presets_agree():
     lay2 = TOPOLOGY_PRESETS["tpu_pod_x2"].axis_layout(
         16, cost_preset="tpu_v5e")
     assert lay2["devices_total"] == 512 and lay2["pods"] == 2
+
+
+def test_published_widths_resolve_to_the_chip_share_cut():
+    """reduced=False on a live mode is the arch's chip_share() cut: the
+    published widths, depth cut to one pipeline stage, device-free."""
+    from repro.configs import gpt_paper
+
+    sess = session("gpt_paper", reduced=False, seq_len=1024)
+    cfg, rc = sess.cfg, sess.rc
+    full = gpt_paper.config("1.5B")
+    assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab) == (
+        full.d_model, full.n_heads, full.d_ff, full.vocab) == (
+        2304, 24, 9216, 50304)
+    cut = gpt_paper.CHIP_SHARE["reduced"]["n_layers"]
+    assert (full.n_layers, cfg.n_layers) == (cut["published"], cut["here"])
+    assert (rc.pp, rc.schedule) == (1, "zeropp")
+    assert sess.describe()["geometry"]["model_ranks"] == 1
+
+
+def test_attached_topology_reads_the_live_host():
+    import jax
+
+    from repro.runtime.topology import attached_topology
+
+    t = attached_topology()
+    dev = jax.devices()
+    assert t.kind == {"cpu": "fake_cpu", "tpu": "tpu_host"}[dev[0].platform]
+    assert t.total_devices == len(dev)
+    assert t.describe()["device_kind"] == dev[0].device_kind
+    assert t.axis_layout(1)["data"] == len(dev)
+
+
+@pytest.mark.parametrize("outside", [True, False])
+def test_compile_cache_placement(monkeypatch, outside):
+    """$JAX_COMPILATION_CACHE_DIR (or a directory already configured)
+    wins; otherwise the cache goes to the fixed in-checkout path."""
+    import jax
+
+    from repro.api import devices
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if outside:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        devices._place_compile_cache()
+        placed = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert placed == (None if outside else str(devices.CACHE_DIR))
+    assert devices.CACHE_DIR.parent.joinpath("src", "repro").is_dir()

@@ -50,6 +50,32 @@ def test_flash_attention_matches_naive_oracle():
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+def test_flash_attention_vjp_is_the_reference_vjp():
+    """The kernel's custom_vjp backward is the jnp reference's vjp, and
+    each traced backward is counted under ``ref_attention_bwd``."""
+    from repro.kernels import ops
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (1, 128, 4, 32))
+    k = jax.random.normal(ks[1], (1, 128, 2, 32))
+    v = jax.random.normal(ks[2], (1, 128, 2, 32))
+    w = jax.random.normal(ks[3], (1, 128, 4, 32))
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v) * w).sum()
+
+    before = ops.kernel_counters().get("ref_attention_bwd", 0)
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=64, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    assert ops.kernel_counters()["ref_attention_bwd"] == before + 1
+    want = jax.grad(loss(lambda q, k, v: ref.attention(q, k, v,
+                                                       causal=True)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=1e-5)
+
+
 @propcase(n_cases=8)
 def test_selective_scan_sweep(draw):
     b = draw.ints(1, 2)
@@ -99,9 +125,13 @@ def test_fused_xent_sweep(draw):
     h = jax.random.normal(ks[0], (n, d)) * 0.5
     w = jax.random.normal(ks[1], (d, v)) * 0.1
     labels = jax.random.randint(ks[2], (n,), 0, v)
-    loss_k, (dh_k, dw_k) = softmax_xent(h, w, labels, block_n=128,
-                                        block_v=256, interpret=True)
-    loss_r, (dh_r, dw_r) = ref.softmax_xent(h, w, labels, chunk=256)
+    # the training loss masks padded positions out
+    mask = (jnp.arange(n) % draw.choice([1, 3]) == 0).astype(jnp.float32)
+    loss_k, (dh_k, dw_k) = softmax_xent(h, w, labels, mask=mask,
+                                        block_n=128, block_v=256,
+                                        interpret=True)
+    loss_r, (dh_r, dw_r) = ref.softmax_xent(h, w, labels, chunk=256,
+                                            mask=mask)
     assert abs(float(loss_k) - float(loss_r)) < 1e-4
     np.testing.assert_allclose(dh_k, dh_r, atol=1e-5)
     np.testing.assert_allclose(dw_k, dw_r, atol=1e-5)
