@@ -3,7 +3,8 @@ XLA_FLAGS=--xla_force_host_platform_device_count=N (tests must not pollute
 the main process's device count).
 
 Usage: python -m tests.spmd_case <case_name> [arch]
-Prints "CASE_OK <name>" on success.
+Prints "CASE_OK <name>" on success. Tests run the cases through
+tests/spmd_run.py.
 """
 
 import os

@@ -5,9 +5,6 @@ Session defers all mesh/device work). The numerical parity check runs in
 a subprocess with 8 fake devices, like the other SPMD cases.
 """
 
-import subprocess
-import sys
-
 import pytest
 
 from repro.api import (
@@ -23,8 +20,7 @@ from repro.api import (
     session,
 )
 from repro.models.common import ModelConfig, RunConfig
-
-TIMEOUT = 1200
+from tests.spmd_run import run_case
 
 
 # --------------------------------------------------------------------------- #
@@ -333,21 +329,8 @@ def test_describe_uses_simulator_not_tick_counts():
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.slow
 def test_session_matches_hand_assembled_path():
-    import os
-
-    cmd = [sys.executable, "-m", "tests.spmd_case", "api_parity",
-           "llama3.2-1b"]
-    p = subprocess.run(
-        cmd, capture_output=True, text=True, timeout=TIMEOUT,
-        env={**os.environ, "PYTHONPATH": "src"},
-        cwd=os.path.dirname(os.path.dirname(__file__)),
-    )
-    assert "CASE_OK api_parity" in p.stdout, (
-        f"api_parity failed\n--- stdout ---\n{p.stdout[-3000:]}"
-        f"\n--- stderr ---\n{p.stderr[-3000:]}"
-    )
+    run_case("api_parity", "llama3.2-1b")
 
 
 # --------------------------------------------------------------------------- #

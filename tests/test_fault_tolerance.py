@@ -18,7 +18,7 @@ from repro.runtime.fault_tolerance import (
     FaultToleranceConfig,
     TrainController,
 )
-from tests.test_pipeline_equiv import _run
+from tests.spmd_run import run_case
 
 
 # --------------------------------------------------------------------------- #
@@ -140,15 +140,15 @@ def test_summary_and_attach_surface_in_describe():
 def test_elastic_train_shrinks_topology_bit_exact():
     """Injected failure mid-run -> restart on a data-halved topology;
     the post-restore loss trajectory is bit-exact vs a clean restore."""
-    _run("elastic_train", "llama3.2-1b")
+    run_case("elastic_train", "llama3.2-1b")
 
 
 def test_serve_reshard_zero_drops_token_identical():
     """ServeEngine.reshard parks a staggered in-flight workload and
     re-admits it on the shrunk mesh with identical token streams."""
-    _run("serve_reshard", "llama3.2-1b")
+    run_case("serve_reshard", "llama3.2-1b")
 
 
 def test_router_two_replicas_token_identical_with_failover():
     """EngineRouter: 2 replicas ≡ 1 engine; replica kill moves work."""
-    _run("router_equiv", "llama3.2-1b")
+    run_case("router_equiv", "llama3.2-1b")

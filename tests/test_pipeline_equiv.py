@@ -1,157 +1,38 @@
-"""Pipeline-vs-reference equivalence and serving tests.
-
-Each case runs in a subprocess with its own fake-device count so the main
-pytest process keeps a single CPU device (per the brief). The cases live
-in tests/spmd_case.py and print CASE_OK on success; the subprocess output
-is attached to failures.
-"""
-
-import subprocess
-import sys
+"""Schedule equivalence: every baseline schedule, both §4 autogen tables
+and the auto-selected schedule run through the same tick engine, with the
+reference's gradients, as cases of tests/spmd_case.py."""
 
 import pytest
 
-TIMEOUT = 1200
+from tests.spmd_run import run_case
 
 
-def _run(case: str, *args: str, env_extra: dict | None = None):
-    cmd = [sys.executable, "-m", "tests.spmd_case", case, *args]
-    p = subprocess.run(
-        cmd, capture_output=True, text=True, timeout=TIMEOUT,
-        env={**__import__("os").environ, "PYTHONPATH": "src",
-             **(env_extra or {})},
-        cwd=__import__("os").path.dirname(
-            __import__("os").path.dirname(__file__)),
-    )
-    ok = f"CASE_OK {case}" in p.stdout
-    if not ok:
-        raise AssertionError(
-            f"{case} {args} failed\n--- stdout ---\n{p.stdout[-3000:]}"
-            f"\n--- stderr ---\n{p.stderr[-3000:]}"
-        )
-
-
-ALL_ARCHS = [
-    "llama3.2-1b", "yi-9b", "minitron-4b", "phi4-mini-3.8b",
-    "phi-3-vision-4.2b", "qwen2-moe-a2.7b", "deepseek-v3-671b",
-    "jamba-v0.1-52b", "xlstm-1.3b", "whisper-large-v3", "gpt_paper",
-]
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("arch", ALL_ARCHS)
-def test_train_equivalence(arch):
-    """Pipeline gradients == jax.grad(reference) for every architecture."""
-    _run("train_equiv", arch)
-
-
-@pytest.mark.slow
 @pytest.mark.parametrize("schedule", ["bfs", "gpipe", "1f1b", "autogen",
                                       "autogen_gated"])
 def test_baseline_schedules_equivalence(schedule):
     """Every baseline (and both §4 autogen tables) runs through the same
     tick engine, exactly."""
-    _run("train_equiv", "llama3.2-1b", f"schedule={schedule}")
+    run_case("train_equiv", "llama3.2-1b", f"schedule={schedule}")
 
 
-@pytest.mark.slow
 def test_gated_autogen_bitwise_parity_and_memory():
     """ISSUE-5 acceptance: "autogen_gated" keeps unit-depth stash buffers,
     its gradients are bit-identical to the zeropp baseline on the smoke
     config, and its simulated peak memory is strictly below full-depth
     autogen."""
-    _run("gated_autogen_parity", "llama3.2-1b")
+    run_case("gated_autogen_parity", "llama3.2-1b")
 
 
-@pytest.mark.slow
 def test_executor_matches_seed_bit_for_bit():
     """The extracted tick engine must reproduce the recorded seed
     executor's train grads/metrics and served tokens bit-for-bit.
     PYTHONHASHSEED is pinned: trace-time set iteration order is the only
     run-to-run variance in this fully-deterministic CPU setup."""
-    _run("golden_parity", "llama3.2-1b",
-         env_extra={"PYTHONHASHSEED": "0"})
+    run_case("golden_parity", "llama3.2-1b",
+             env_extra={"PYTHONHASHSEED": "0"})
 
 
-@pytest.mark.slow
 def test_auto_schedule_trains_and_serves():
     """session(arch, schedule="auto"): picks the min-makespan plan among
     every registered schedule, then trains and serves with it."""
-    _run("auto_schedule", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_multi_pod_equivalence():
-    _run("train_equiv", "llama3.2-1b", "pod=2", "data=2")
-
-
-@pytest.mark.slow
-def test_ep_moe_equivalence():
-    _run("train_equiv", "deepseek-v3-671b", "moe_mode=ep")
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("arch", ["llama3.2-1b", "qwen2-moe-a2.7b"])
-def test_pipeline_loss_decreases(arch):
-    _run("loss_decreases", arch)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("arch", ["llama3.2-1b", "xlstm-1.3b",
-                                  "qwen2-moe-a2.7b", "jamba-v0.1-52b",
-                                  "deepseek-v3-671b"])
-def test_serve_decode_matches_reference(arch):
-    """Greedy continuation through the cached serving pipeline equals the
-    reference model's — covers GQA, mLSTM/sLSTM, gathered MoE, Mamba
-    hybrid and MLA compressed-KV decode paths."""
-    _run("serve_decode", arch)
-
-
-@pytest.mark.slow
-def test_hlo_collective_structure():
-    """§3.3 comm counts realized in the compiled HLO."""
-    _run("hlo_gather_count", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_gather_prefetch_is_numerically_neutral():
-    _run("prefetch_equiv", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_flat_coalesce_bitwise_parity():
-    """coalesce="flat" (ONE all-gather / reduce-scatter per tick) must be
-    bit-identical to per-tensor collectives: train grads + serve tokens."""
-    _run("flat_parity", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_flat_int8_error_feedback_reduce():
-    """grad_compress="int8" through the flat reduce: one int32
-    psum_scatter with a segment-wide shared scale + error feedback."""
-    _run("flat_int8", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_flat_fallback_mixed_divisibility():
-    """Replicated (non-divisible) tensors fall back to per-tensor
-    collectives bit-identically, incl. an ld != 0 flat-pack member."""
-    _run("flat_fallback", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_buffer_donation_audit():
-    """Serve step donates caches, opt step donates params + opt state —
-    input/output aliasing visible in the lowered modules."""
-    _run("donation", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_int8_grad_reduction():
-    _run("int8_grads", "llama3.2-1b")
-
-
-@pytest.mark.slow
-def test_elastic_reshard_resume():
-    """Checkpoint at D=4, restore and continue at D=2."""
-    _run("elastic_reshard", "llama3.2-1b")
+    run_case("auto_schedule", "llama3.2-1b")

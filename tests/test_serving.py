@@ -11,7 +11,7 @@ tests/spmd_case.py, like the other pipeline tests.
 import numpy as np
 import pytest
 
-from tests.test_pipeline_equiv import _run
+from tests.spmd_run import run_case
 
 
 # --------------------------------------------------------------------------- #
@@ -867,22 +867,19 @@ def test_spec_paged_knobs_validate():
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.slow
 def test_batched_equals_sequential_serving():
     """The issue's correctness bar: token-identical engine output for a
     staggered 8-request workload vs independent sequential serving, with
     slot reclaim/refill mid-decode and chunked prefill."""
-    _run("serving_engine_equiv", "llama3.2-1b")
+    run_case("serving_engine_equiv", "llama3.2-1b")
 
 
-@pytest.mark.slow
 def test_train_serve_handoff_roundtrip():
     """mode='serve' sessions boot from a train checkpoint with
     cache-aware relayout; tokens equal a direct param transplant."""
-    _run("serve_handoff", "llama3.2-1b")
+    run_case("serve_handoff", "llama3.2-1b")
 
 
-@pytest.mark.slow
 def test_paged_equals_contiguous_serving():
     """ISSUE-6 correctness bar: greedy paged decoding is token-identical
     to the contiguous path on the staggered 8-request workload (with
@@ -895,8 +892,8 @@ def test_paged_equals_contiguous_serving():
     iteration can reorder accumulation enough to flip a near-tie argmax
     between the paged and contiguous programs (int8 perturbs logits by
     O(0.5%) — the API.md caveat; seed 2 reproduces the flip)."""
-    _run("serving_paged_equiv", "llama3.2-1b",
-         env_extra={"PYTHONHASHSEED": "0"})
+    run_case("serving_paged_equiv", "llama3.2-1b",
+             env_extra={"PYTHONHASHSEED": "0"})
 
 
 # --------------------------------------------------------------------------- #

@@ -2,6 +2,7 @@
 
 import os
 import shutil
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,28 @@ from repro.runtime.fault_tolerance import (
     TrainController,
 )
 from tests.proptest import propcase
+
+WAIT_LIMIT_S = 60
+
+
+def _within_limit(fn):
+    """Return fn() from a daemon thread, failing the test if it has not
+    returned after WAIT_LIMIT_S: a wait that hangs fails this test alone."""
+    out = {}
+
+    def call():
+        try:
+            out["value"] = fn()
+        except Exception as e:  # re-raised in the test's thread
+            out["error"] = e
+
+    t = threading.Thread(target=call, daemon=True)
+    t.start()
+    t.join(WAIT_LIMIT_S)
+    assert not t.is_alive(), f"{fn} still waiting after {WAIT_LIMIT_S} s"
+    if "error" in out:
+        raise out["error"]
+    return out["value"]
 
 
 # --------------------------------------------------------------------------- #
@@ -73,9 +96,9 @@ def test_data_stream_deterministic_and_elastic():
 def test_prefetcher_cursor():
     cfg = DataConfig(seq_len=8, global_batch=2, vocab=31)
     pf = Prefetcher(SyntheticStream(cfg), start_step=5)
-    s, b = next(pf)
+    s, b = _within_limit(lambda: next(pf))
     assert s == 5
-    s, b = next(pf)
+    s, b = _within_limit(lambda: next(pf))
     assert s == 6
     assert pf.state()["step"] == 7
     pf.close()
