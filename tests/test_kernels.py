@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, fwd_plan
 from repro.kernels.fused_xent import softmax_xent
 from repro.kernels.paged_attention import (
     decode_attention as pallas_decode_attention,
@@ -28,26 +28,75 @@ def test_flash_attention_sweep(draw):
     sk = draw.choice([sq, 2 * sq])
     causal = draw.bool() if sq == sk else False
     dtype = draw.choice([jnp.float32, jnp.bfloat16])
+    # explicit blocks (equal or not) or the shape-derived default
+    block_q = draw.choice([64, 128, None])
+    block_k = draw.choice([64, 128, None])
     q = jax.random.normal(jax.random.PRNGKey(draw.ints(0, 99)),
                           (b, sq, h, e)).astype(dtype)
     k = jax.random.normal(jax.random.PRNGKey(1), (b, sk, g, e)).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, sk, g, ev)).astype(dtype)
-    got = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
-                          interpret=True)
+    got = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k, interpret=True)
     want = ref.attention(q, k, v, causal=causal, block_k=128)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol)
 
 
-def test_flash_attention_matches_naive_oracle():
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 4, 32))
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, 128, 2, 32))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 128, 2, 32))
-    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+# (b, sq, sk, h, g, e, ev, causal, block_q, block_k)
+TILINGS = {
+    "gqa_blocks64": (1, 128, 128, 4, 2, 32, 32, True, 64, 64),
+    "causal_bq_gt_bk": (1, 256, 256, 4, 2, 32, 32, True, 128, 64),
+    "causal_bq_lt_bk": (1, 256, 256, 4, 2, 32, 32, True, 64, 128),
+    "sq200_neither_block": (1, 200, 200, 4, 4, 32, 32, True, 64, 128),
+    "sq1000_default_blocks": (1, 1000, 1000, 2, 1, 32, 32, True, None, None),
+    "gqa_rep4_ev_ne_e": (2, 200, 200, 8, 2, 64, 32, True, None, None),
+    "e96_default_blocks": (1, 512, 512, 8, 8, 96, 96, True, None, None),
+    "cross_sk_ne_sq": (1, 200, 300, 4, 2, 32, 16, False, None, None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(TILINGS))
+def test_flash_attention_matches_naive_oracle(case, dtype):
+    """Causal masking over unequal blocks, ragged sequences, GQA,
+    ev != e and the default blocks: f32 against the O(S²) oracle at 1e-5, bf16 against the
+    float32 streaming reference at the sweep's 2e-2."""
+    b, sq, sk, h, g, e, ev, causal, bq, bk = TILINGS[case]
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    dt = jnp.dtype(dtype)
+    q = jax.random.normal(ks[0], (b, sq, h, e)).astype(dt)
+    k = jax.random.normal(ks[1], (b, sk, g, e)).astype(dt)
+    v = jax.random.normal(ks[2], (b, sk, g, ev)).astype(dt)
+    got = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
                           interpret=True)
-    want = ref.attention_naive(q, k, v, causal=True)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+    if dt == jnp.float32:
+        want, atol = ref.attention_naive(q, k, v, causal=causal), 1e-5
+    else:
+        want, atol = ref.attention(q, k, v, causal=causal), 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["default", "given"])
+@pytest.mark.parametrize("shape,blocks", [
+    (((1, 1024, 24, 96), (1, 1024, 24, 96)), (1024, 1024)),   # GPT 1.5B
+    (((1, 1024, 32, 64), (1, 1024, 8, 64)), (1024, 1024)),    # GQA 32/8
+    (((2, 448, 20, 64), (2, 1500, 20, 64)), (448, 768)),      # cross
+    (((1, 4096, 8, 128), (1, 4096, 8, 128)), (1024, 1024)),   # long
+], ids=["gpt", "gqa", "cross", "long"])
+def test_flash_fwd_plan_engagement(shape, blocks, given):
+    """The forward's tiling is a pure function of the shapes: each
+    sequence in the fewest blocks of at most 1024 rows, evened out, and
+    explicit blocks are honoured. At the GPT 1.5B training shape that is
+    one block per head: 24 grid steps a call."""
+    (b, sq, h, _), (_, sk, _, _) = shape
+    if given:
+        blocks = (64, 128)
+    plan = fwd_plan(*shape, block_q=blocks[0] if given else None,
+                    block_k=blocks[1] if given else None)
+    assert (plan.block_q, plan.block_k) == blocks
+    assert plan.grid == (b * h, -(-sq // blocks[0]), -(-sk // blocks[1]))
 
 
 def test_flash_attention_vjp_is_the_reference_vjp():

@@ -59,12 +59,18 @@ def _compile(fn, chip, *shapes):
     return hlo
 
 
-def test_flash_attention_fwd_and_custom_vjp_bwd(chip):
-    qkv = [((1, SEQ, H, E), jnp.bfloat16)] * 3
+@pytest.mark.parametrize("shape,causal", [
+    (((1, SEQ, H, E), (1, SEQ, H, E), (1, SEQ, H, E)), True),
+    (((1, SEQ, 32, 64), (1, SEQ, 8, 64), (1, SEQ, 8, 64)), True),  # GQA
+    (((1, SEQ, 16, 192), (1, SEQ, 16, 192), (1, SEQ, 16, 128)), True),  # MLA
+    (((1, 448, 20, 64), (1, 1500, 20, 64), (1, 1500, 20, 64)), False),
+], ids=["gpt", "gqa", "mla", "whisper_cross"])
+def test_flash_attention_fwd_and_custom_vjp_bwd(chip, shape, causal):
+    qkv = [(s, jnp.bfloat16) for s in shape]
 
     def fwd_bwd(q, k, v):
         out, vjp = jax.vjp(
-            lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+            lambda q, k, v: flash_attention(q, k, v, causal=causal), q, k, v)
         return out, vjp(out)
 
     _compile(fwd_bwd, chip, *qkv)
