@@ -74,14 +74,15 @@ def main(argv=None) -> int:
                                   {"fault": "half_batch"}),
                                  ("ref_grad_altered",
                                   {"fault": "grad_altered"})):
-                    got = model.train_reference(m, seed, batches,
-                                                t["optim"], **kw)
+                    got = model.train_reference(cell.arch, m, seed,
+                                                batches, t["optim"], **kw)
                     emit("READING", seed=seed, kind=what,
                          **runner.compare(got["losses"], got["grad1"],
                                           got["delta"], out["reference"]))
             else:
-                got = model.serve_reference(m, seed, out["sample"],
-                                            t["context"], control="fp8")
+                got = model.serve_reference(cell.arch, m, seed,
+                                            out["sample"], t["context"],
+                                            control="fp8")
                 emit("READING", seed=seed, kind="control_fp8",
                      served_gap=got["control_gap"], tokens=got["tokens"])
         if seed in ints(args.fault_seeds):

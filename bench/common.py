@@ -8,6 +8,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -39,6 +40,55 @@ def load_module(path: str, name: str):
     return mod
 
 
+# What an architecture module exports, by the kind of cell that uses it.
+ARCH_EXPORTS = {
+    "train": ("layer_leaves", "io_leaves", "objective",
+              "train_flops_per_token", "FAULT_LEAF"),
+    "serve": ("layer_leaves", "io_leaves", "logits", "serve_call_flops"),
+}
+_ARCHES: dict = {}   # one module object a file: jitted code keyed by it
+
+
+def load_arch(name: str, kind: str):
+    """The architecture module ``bench/arch/<name>.py`` that a
+    configuration's ``"arch"`` names, holding what belongs to one
+    architecture; everything else in the benchmark is shared. ``m`` is
+    the configuration's "model" block, and the module imports nothing
+    of the program. It exports, for a training cell:
+
+    - ``layer_leaves(m, layer)``: ``{name: (shape, init, fan_in)}``, the
+      leaves of layer ``layer`` (layers may differ); init is "normal"
+      (scaled by 1/sqrt(fan_in)), "ones" or "zeros";
+    - ``io_leaves(m)``: the same for the embedding, final norm and head;
+    - ``objective(m, params, tokens, labels, mm)`` -> ``(reported,
+      differentiated)``: ``reported`` is the loss per token that the
+      program's ``loss_sum`` reports; ``differentiated`` adds any term
+      the program's gradients carry and ``loss_sum`` leaves out (a
+      router's balance term, say). ``params`` is the reference's layout
+      (``model.reference_params``), ``mm(eq, a, b)`` the matrix product
+      (``model.make_mm``: float32, or float8 for the control);
+    - ``train_flops_per_token(m, seq)``: model FLOPs of one trained
+      token, forward and backward, no recomputation counted;
+    - ``FAULT_LEAF``: the layer leaf whose gradient the "grad_altered"
+      fault doubles;
+
+    and for a serving cell ``layer_leaves``, ``io_leaves``,
+    ``logits(m, params, tokens, mm)`` -> float32 ``[b, s, vocab]``, and
+    ``serve_call_flops(m, width, positions)``: model FLOPs of one serve
+    step of ``width`` tokens a row, rows starting at ``positions``."""
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise BenchError(f"architecture {name!r} is not a module name")
+    path = bench_file("arch", name + ".py")
+    if path not in _ARCHES:
+        _ARCHES[path] = load_module(path, "arch_" + name)
+    mod = _ARCHES[path]
+    missing = [x for x in ARCH_EXPORTS[kind] if not hasattr(mod, x)]
+    if missing:
+        raise BenchError(f"bench/arch/{name}.py lacks {missing}, which a "
+                         f"{kind} cell needs")
+    return mod
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     """One workload of BENCHMARK.json with its files loaded."""
@@ -46,6 +96,7 @@ class Cell:
     name: str
     chips: int
     config: dict      # bench/configs/<config>.json
+    arch: object      # bench/arch/<config's "arch">.py
     traffic: dict     # bench/traffic/<traffic>.json
     limits: dict      # bench/limits/<workload>.json
     end_to_end: tuple  # metric entries of BENCHMARK.json this cell reports
@@ -73,8 +124,11 @@ def load_cell(name: str, spec: dict | None = None) -> Cell:
             load_json(bench_file("traffic", w["traffic"] + ".json")))
     except ValueError as e:
         raise BenchError(f"traffic {w['traffic']!r}: {e}") from None
+    if "arch" not in config:
+        raise BenchError(f"configuration {w['config']!r} names no \"arch\"")
     return Cell(
-        name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+        name=name, chips=int(w["chips"]), config=config,
+        arch=load_arch(config["arch"], traffic["kind"]), traffic=traffic,
         limits=load_json(bench_file("limits", name + ".json")),
         end_to_end=tuple(m for m in spec["end_to_end"] if _reports(m, name)),
         per_layer=tuple(m for m in spec["per_layer"] if _reports(m, name)))

@@ -1,6 +1,7 @@
-"""Operations and bytes from shapes: the yardstick of every utilisation
-and roofline share the benchmark reports. ``m`` is a configuration
-file's "model" block."""
+"""Operations and bytes of the kernels from their shapes: the yardstick
+of every roofline share the benchmark reports. ``m`` is a configuration
+file's "model" block. A whole step's model FLOPs belong to its
+architecture (``bench/arch/<name>.py``)."""
 
 from __future__ import annotations
 
@@ -9,14 +10,6 @@ def dims(m: dict):
     d, h, g = m["d_model"], m["n_heads"], m["n_kv_heads"]
     return d, h, g, m["d_head"] or d // h, m["d_ff"], m["vocab"], \
         m["n_layers"]
-
-
-def train_flops_per_token(m: dict, seq: int) -> float:
-    """Model FLOPs of one trained token, forward and backward, with no
-    recomputation counted (Narayanan et al. 2021, eq. 3 without the
-    recompute pass): 72 L h^2 (1 + s / 6h + V / 12 h L)."""
-    d, _, _, _, _, v, n = dims(m)
-    return 72.0 * n * d * d * (1 + seq / (6.0 * d) + v / (12.0 * d * n))
 
 
 def flash_fwd(m: dict, batch: int, seq: int, elem: int = 2):
@@ -52,15 +45,6 @@ def paged_attn_call(m: dict, width: int, positions, elem: int = 2):
         nbytes += elem * ((p + width) * 2 * g * e + width * 2 * g * e
                           + 2 * width * h * e)
     return flops, nbytes
-
-
-def serve_call_flops(m: dict, width: int, positions) -> float:
-    """Model FLOPs of one serve step over its rows: every token through
-    every layer's matrices and attention, and the head once per row."""
-    d, h, g, e, f, v, n = dims(m)
-    per_token = 2.0 * n * (2 * d * h * e + 2 * d * g * e + 2 * d * f)
-    attn, _ = paged_attn_call(m, width, positions)
-    return len(positions) * (width * per_token + 2.0 * d * v) + n * attn
 
 
 def roofline_s(flops: float, nbytes: float, peaks: dict) -> float:

@@ -1,16 +1,18 @@
-"""Weights from the seed, and the plain reference of the GPT that the
-configuration files describe.
+"""Weights from the seed, the program's stacked layout, and the plain
+reference of whatever architecture a configuration names.
 
 Both the program and the reference take their weights from ``leaf()``:
 the program in its own stacked pipeline layout, built on the device in
 one jitted call; the reference one leaf at a time, as float32 copies of
 the same bfloat16 numbers. The reference imports nothing of the program.
 
-The GPT here is the one the program runs: pre-norm layers, LayerNorm
-(eps 1e-5) with scale and bias, multi-head attention with rotary
-positions (rotate-half, theta from the configuration) and no projection
-bias, a tanh-GELU MLP, a final LayerNorm and an untied head. Loss is the
-mean next-token cross-entropy over every token of the batch.
+What belongs to one architecture (its leaves per layer, its layer, its
+loss) is the module ``bench/arch/<name>.py`` (``common.load_arch``);
+every function here takes that module as ``arch``. Layers may have
+different leaves. The reference's layout is ``{"io": {name: a},
+"layers": [group, ...]}``: each group stacks one run of consecutive
+layers with equal leaf tables, ``{name: [n, ...]}``, for a scan
+(``scan_layers``); a model whose layers are all alike is one group.
 """
 
 from __future__ import annotations
@@ -29,30 +31,20 @@ import numpy as np
 # --------------------------------------------------------------------------- #
 
 
-def layer_leaves(m: dict) -> dict:
-    """Per-layer leaves: name -> (shape, init, fan_in)."""
-    d, h, g, f = m["d_model"], m["n_heads"], m["n_kv_heads"], m["d_ff"]
-    e = m["d_head"] or d // h
-    return {
-        "ln1.scale": ((d,), "ones", 0), "ln1.bias": ((d,), "zeros", 0),
-        "mix.wq": ((d, h, e), "normal", d),
-        "mix.wk": ((d, g, e), "normal", d),
-        "mix.wv": ((d, g, e), "normal", d),
-        "mix.wo": ((h, e, d), "normal", h * e),
-        "ln2.scale": ((d,), "ones", 0), "ln2.bias": ((d,), "zeros", 0),
-        "ffn.wi": ((d, f), "normal", d),
-        "ffn.wd": ((f, d), "normal", f),
-    }
+def layer_tables(arch, m: dict) -> list:
+    """Each layer's leaf table, ``arch.layer_leaves(m, layer)``."""
+    return [arch.layer_leaves(m, i) for i in range(m["n_layers"])]
 
 
-def io_leaves(m: dict) -> dict:
-    d, v = m["d_model"], m["vocab"]
-    return {
-        "embed.table": ((v, d), "normal", 1),
-        "final_norm.scale": ((d,), "ones", 0),
-        "final_norm.bias": ((d,), "zeros", 0),
-        "head.w": ((d, v), "normal", d),
-    }
+def layer_runs(tables: list) -> list:
+    """(first layer, layers) of each run of consecutive equal tables."""
+    runs = []
+    for i, t in enumerate(tables):
+        if runs and tables[runs[-1][0]] == t:
+            runs[-1][1] += 1
+        else:
+            runs.append([i, 1])
+    return [tuple(r) for r in runs]
 
 
 def seed_key(seed: int):
@@ -102,29 +94,49 @@ def layer_of(geo, seg, row: int, slot: int) -> int:
     return stage_layers(geo, seg)[row] * seg.k + slot
 
 
-def check_layout(m: dict, shapes) -> None:
-    """The program's parameter tree must hold exactly this GPT's leaves."""
-    io, lw = io_leaves(m), layer_leaves(m)
+def check_layout(arch, m: dict, geo, shapes) -> None:
+    """The program's parameter tree must hold exactly the configuration's
+    leaves: the io leaves, and in each row of each layer slot the leaves
+    of the layer that row holds (rows of padding layers hold zeros and
+    are not checked)."""
+    io, tables = arch.io_leaves(m), layer_tables(arch, m)
     got_io = {n: tuple(s.shape) for n, s in shapes["io"].items()}
     want_io = {n: spec[0] for n, spec in io.items()}
     if got_io != want_io:
         raise ValueError(f"program io leaves {got_io} are not the "
                          f"configuration's {want_io}")
+    segs = {s.name: s for s in geo.segments}
+    held = [set() for _ in tables]
     for seg_name, leaves in shapes["segments"].items():
         for full, s in leaves.items():
             mt = _SLOT.fullmatch(full)
-            if not mt or mt.group(2) not in lw \
-                    or tuple(s.shape[1:]) != lw[mt.group(2)][0]:
-                raise ValueError(f"program leaf {seg_name}.{full} "
-                                 f"{tuple(s.shape)} is not a layer leaf of "
-                                 "the configuration")
+            if not mt:
+                raise ValueError(f"program leaf {seg_name}.{full} is not "
+                                 "in a layer slot")
+            slot, name = int(mt.group(1)), mt.group(2)
+            for row in range(s.shape[0]):
+                layer = layer_of(geo, segs[seg_name], row, slot)
+                if layer >= len(tables):
+                    continue
+                spec = tables[layer].get(name)
+                if spec is None or tuple(s.shape[1:]) != spec[0]:
+                    raise ValueError(
+                        f"program leaf {seg_name}.{full} {tuple(s.shape)} "
+                        f"is not a leaf of layer {layer} of the "
+                        "configuration")
+                held[layer].add(name)
+    for layer, (names, table) in enumerate(zip(held, tables)):
+        if names != set(table):
+            raise ValueError(f"layer {layer}: the program holds "
+                             f"{sorted(names)}, the configuration "
+                             f"{sorted(table)}")
 
 
-def program_tree(m: dict, geo, shapes):
+def program_tree(arch, m: dict, geo, shapes):
     """``build(key)``: the program's parameter tree from a key, in its
     stacked layout (traceable; rows of padding layers are zeros)."""
-    check_layout(m, shapes)
-    io, lw = io_leaves(m), layer_leaves(m)
+    check_layout(arch, m, geo, shapes)
+    io, tables = arch.io_leaves(m), layer_tables(arch, m)
     segs = {s.name: s for s in geo.segments}
 
     def build(key):
@@ -139,7 +151,7 @@ def program_tree(m: dict, geo, shapes):
                 for row in range(s.shape[0]):
                     layer = layer_of(geo, seg, row, int(slot))
                     rows.append(
-                        leaf(key, layer, name, lw[name], s.dtype)
+                        leaf(key, layer, name, tables[layer][name], s.dtype)
                         if layer < m["n_layers"]
                         else jnp.zeros(s.shape[1:], s.dtype))
                 out["segments"][seg_name][full] = jnp.stack(rows)
@@ -148,41 +160,58 @@ def program_tree(m: dict, geo, shapes):
     return build
 
 
-def program_maker(m: dict, geo, shapes):
+def program_maker(arch, m: dict, geo, shapes):
     """``make(key)``: one jitted call that makes the program's parameter
     tree on its devices, in the dtype and sharding it serves them in.
     Calling the same ``make`` again gives the same bits; another program
     that makes them (fused otherwise) may round a few differently."""
-    return jax.jit(program_tree(m, geo, shapes),
+    return jax.jit(program_tree(arch, m, geo, shapes),
                    out_shardings=jax.tree.map(lambda s: s.sharding, shapes))
 
 
-def program_params(seed: int, m: dict, geo, shapes):
+def program_params(arch, seed: int, m: dict, geo, shapes):
     """The program's parameter tree from the seed (``program_maker``)."""
-    return program_maker(m, geo, shapes)(seed_key(seed))
+    return program_maker(arch, m, geo, shapes)(seed_key(seed))
 
 
 # --------------------------------------------------------------------------- #
-# The reference's layout: {"io": {name: a}, "layers": {name: [L, ...]}}
+# The reference's layout: {"io": {name: a}, "layers": [{name: [n, ...]}]}
 # --------------------------------------------------------------------------- #
 
 
-def reference_params(seed: int, m: dict, dtype=jnp.float32):
+def reference_params(arch, seed: int, m: dict, dtype=jnp.float32):
     """The same weights as ``program_params``, as float32 copies of the
-    bfloat16 numbers, layers stacked for a scan."""
-    io, lw = io_leaves(m), layer_leaves(m)
+    bfloat16 numbers, each run of alike layers stacked for a scan."""
+    io, tables = arch.io_leaves(m), layer_tables(arch, m)
 
     @jax.jit
     def build(key):
         return {
             "io": {n: leaf(key, -1, n, s).astype(dtype)
                    for n, s in io.items()},
-            "layers": {n: jnp.stack([leaf(key, i, n, s).astype(dtype)
-                                     for i in range(m["n_layers"])])
-                       for n, s in lw.items()},
+            "layers": [{n: jnp.stack([leaf(key, i, n, s).astype(dtype)
+                                      for i in range(first, first + k)])
+                        for n, s in tables[first].items()}
+                       for first, k in layer_runs(tables)],
         }
 
     return build(seed_key(seed))
+
+
+def scan_layers(layer, x, groups):
+    """``x`` through every layer in order: ``layer(x, p)`` scanned over
+    each group's stacked leaves, rematerialised in the backward pass."""
+    body = jax.checkpoint(lambda x, p: (layer(x, p), None))
+    for g in groups:
+        x, _ = jax.lax.scan(body, x, g)
+    return x
+
+
+def xent(lg, labels):
+    """Mean next-token cross-entropy of logits [b, s, V] at labels."""
+    lse = jax.nn.logsumexp(lg, -1)
+    lab = jnp.take_along_axis(lg, labels[..., None], -1)[..., 0]
+    return (lse - lab).mean()
 
 
 def _q8(a):
@@ -202,75 +231,6 @@ def make_mm(precision: str):
     return mm
 
 
-def _ln(x, scale, bias):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + 1e-5) * scale + bias
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-
-
-def _rope_tables(seq: int, e: int, theta: float):
-    inv = 1.0 / theta ** (np.arange(0, e, 2) / e)
-    ang = np.arange(seq)[:, None] * inv[None, :]
-    return jnp.asarray(np.cos(ang), jnp.float32), \
-        jnp.asarray(np.sin(ang), jnp.float32)
-
-
-def _rope(x, cos, sin):
-    """x [b, s, h, e]; rotate-half over the two halves of e."""
-    e = x.shape[-1]
-    x1, x2 = x[..., :e // 2], x[..., e // 2:]
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
-
-
-def _layer(m, mm, x, p, cos, sin):
-    b, s, d = x.shape
-    h, g = m["n_heads"], m["n_kv_heads"]
-    e = m["d_head"] or d // h
-    a = _ln(x, p["ln1.scale"], p["ln1.bias"])
-    q = _rope(mm("bsd,dhe->bshe", a, p["mix.wq"]), cos, sin)
-    k = _rope(mm("bsd,dge->bsge", a, p["mix.wk"]), cos, sin)
-    v = mm("bsd,dge->bsge", a, p["mix.wv"])
-    k, v = jnp.repeat(k, h // g, axis=2), jnp.repeat(v, h // g, axis=2)
-    sc = mm("bqhe,bkhe->bhqk", q, k) / math.sqrt(e)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    sc = jnp.where(causal[None, None], sc, -jnp.inf)
-    o = mm("bhqk,bkhe->bqhe", jax.nn.softmax(sc, axis=-1), v)
-    x = x + mm("bshe,hed->bsd", o, p["mix.wo"])
-    a = _ln(x, p["ln2.scale"], p["ln2.bias"])
-    return x + mm("bsf,fd->bsd", _gelu(mm("bsd,df->bsf", a, p["ffn.wi"])),
-                  p["ffn.wd"])
-
-
-def logits(m: dict, params, tokens, precision: str = "f32"):
-    """tokens [b, s] -> logits [b, s, vocab], float32."""
-    mm = make_mm(precision)
-    io = params["io"]
-    cos, sin = _rope_tables(tokens.shape[1], m["d_head"] or
-                            m["d_model"] // m["n_heads"], m["rope_theta"])
-    x = io["embed.table"][tokens]
-
-    @jax.checkpoint
-    def body(x, p):
-        return _layer(m, mm, x, p, cos, sin), None
-
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    hn = _ln(x, io["final_norm.scale"], io["final_norm.bias"])
-    return mm("bsd,dv->bsv", hn, io["head.w"])
-
-
-def loss(m: dict, params, tokens, labels, precision: str = "f32"):
-    lg = logits(m, params, tokens, precision)
-    lse = jax.nn.logsumexp(lg, -1)
-    lab = jnp.take_along_axis(lg, labels[..., None], -1)[..., 0]
-    return (lse - lab).mean()
-
-
 # --------------------------------------------------------------------------- #
 # Training reference: AdamW as the configuration states it
 # --------------------------------------------------------------------------- #
@@ -280,30 +240,52 @@ def _decays(name: str, optim: dict) -> bool:
     return not any(t in name for t in optim["no_decay"])
 
 
+def _map_named(f, tree, *rest):
+    """``f(name, leaf, *leaves of rest)`` over a reference-layout tree."""
+    return {"io": {n: f(n, a, *(r["io"][n] for r in rest))
+                   for n, a in tree["io"].items()},
+            "layers": [{n: f(n, a, *(r["layers"][i][n] for r in rest))
+                        for n, a in g.items()}
+                       for i, g in enumerate(tree["layers"])]}
+
+
 def norms(tree) -> dict:
-    """(part, name) -> norm per layer (one entry for io leaves)."""
+    """(part, name) -> norm of each layer that holds the leaf, in layer
+    order (one entry for io leaves)."""
     out = {("io", n): jnp.linalg.norm(a)[None] for n, a in tree["io"].items()}
-    for n, a in tree["layers"].items():
-        out[("layers", n)] = jnp.sqrt(
-            jnp.sum(jnp.square(a.reshape(a.shape[0], -1)), 1))
+    per_layer: dict[str, list] = {}
+    for g in tree["layers"]:
+        for n, a in g.items():
+            per_layer.setdefault(n, []).append(jnp.sqrt(
+                jnp.sum(jnp.square(a.reshape(a.shape[0], -1)), 1)))
+    out.update({("layers", n): jnp.concatenate(v)
+                for n, v in per_layer.items()})
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("m", "precision", "fault"))
-def _step_grad(params, tokens, labels, *, m, precision, fault):
-    """Mean loss over the batch's rows and its gradient; rows run one at
-    a time, as the program's micro-batches do."""
+@functools.partial(jax.jit, static_argnames=("arch", "m", "precision",
+                                             "fault"))
+def _step_grad(params, tokens, labels, *, arch, m, precision, fault):
+    """Mean reported loss over the batch's rows, and the gradient of the
+    mean differentiated one; rows run one at a time, as the program's
+    micro-batches do."""
+    mm = make_mm(precision)
+
     def mean_loss(p):
         def body(acc, row):
             t, lab = row
-            return acc + loss(dict(m), p, t[None], lab[None], precision), None
-        tot, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                              (tokens, labels))
-        return tot / tokens.shape[0]
+            rep, dif = arch.objective(dict(m), p, t[None], lab[None], mm)
+            return (acc[0] + dif, acc[1] + rep), None
+        zero = jnp.float32(0.0)
+        (dif, rep), _ = jax.lax.scan(jax.checkpoint(body), (zero, zero),
+                                     (tokens, labels))
+        return dif / tokens.shape[0], rep / tokens.shape[0]
 
-    lv, g = jax.value_and_grad(mean_loss)(params)
+    (_, lv), g = jax.value_and_grad(mean_loss, has_aux=True)(params)
     if fault == "grad_altered":
-        g["layers"]["ffn.wi"] = g["layers"]["ffn.wi"] * 2.0
+        for grp in g["layers"]:
+            if arch.FAULT_LEAF in grp:
+                grp[arch.FAULT_LEAF] = grp[arch.FAULT_LEAF] * 2.0
     return lv, g
 
 
@@ -316,22 +298,20 @@ def _adamw(params, grads, state, *, step: int, optim):
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     clip = jnp.minimum(1.0, optim["grad_clip"] / jnp.maximum(gnorm, 1e-12))
     b1, b2 = optim["b1"], optim["b2"]
-    new = {"io": {}, "layers": {}}
-    st = {"m": {"io": {}, "layers": {}}, "v": {"io": {}, "layers": {}}}
-    clipped = {"io": {}, "layers": {}}
-    for part in ("io", "layers"):
-        for n, p in params[part].items():
-            g = grads[part][n] * clip
-            mo = b1 * state["m"][part][n] + (1 - b1) * g
-            vo = b2 * state["v"][part][n] + (1 - b2) * g * g
-            delta = (mo / (1 - b1 ** step)) / (
-                jnp.sqrt(vo / (1 - b2 ** step)) + optim["eps"])
-            if _decays(n, optim):
-                delta = delta + optim["weight_decay"] * p
-            new[part][n] = p - optim["lr"] * delta
-            clipped[part][n] = g
-            st["m"][part][n], st["v"][part][n] = mo, vo
-    return new, st, norms(clipped)
+
+    def update(n, p, g, mo, vo):
+        g = g * clip
+        mo = b1 * mo + (1 - b1) * g
+        vo = b2 * vo + (1 - b2) * g * g
+        delta = (mo / (1 - b1 ** step)) / (
+            jnp.sqrt(vo / (1 - b2 ** step)) + optim["eps"])
+        if _decays(n, optim):
+            delta = delta + optim["weight_decay"] * p
+        return p - optim["lr"] * delta, g, mo, vo
+
+    out = _map_named(update, params, grads, state["m"], state["v"])
+    part = lambda i: _map_named(lambda n, t: t[i], out)  # noqa: E731
+    return part(0), {"m": part(2), "v": part(3)}, norms(part(1))
 
 
 class _Frozen(dict):
@@ -350,19 +330,20 @@ def _to_numpy(nrm: dict) -> dict:
     return {k: np.asarray(v, np.float64) for k, v in nrm.items()}
 
 
-def train_reference(m: dict, seed: int, batches, optim: dict,
+def train_reference(arch, m: dict, seed: int, batches, optim: dict,
                     precision: str = "f32", fault: str | None = None):
     """The first ``len(batches)`` steps from the seed's weights: each
-    step's loss, the norms of the first step's clipped gradient, and the
-    norms of each leaf's change after the last step (per layer).
+    step's reported loss, the norms of the first step's clipped
+    gradient, and the norms of each leaf's change after the last step
+    (per layer that holds it).
 
     ``fault`` plants a fault a training step can have, in the reference
     put in the program's place: "half_batch" (half of the rows left out,
-    the mean taken over the rest) or "grad_altered" (one leaf's gradient
-    doubled where it is made)."""
+    the mean taken over the rest) or "grad_altered" (the gradient of
+    ``arch.FAULT_LEAF`` doubled where it is made)."""
     mh, opt = freeze(m), freeze(optim)
     with jax.default_matmul_precision("highest"):
-        params = reference_params(seed, m)
+        params = reference_params(arch, seed, m)
         state = {k: jax.tree.map(jnp.zeros_like, params) for k in "mv"}
         losses, grad1 = [], None
         for step, batch in enumerate(batches, 1):
@@ -370,7 +351,7 @@ def train_reference(m: dict, seed: int, batches, optim: dict,
             if fault == "half_batch":
                 toks, labs = toks[: len(toks) // 2], labs[: len(labs) // 2]
             lv, grads = _step_grad(params, jnp.asarray(toks),
-                                   jnp.asarray(labs), m=mh,
+                                   jnp.asarray(labs), arch=arch, m=mh,
                                    precision=precision, fault=fault)
             losses.append(float(lv))
             params, state, gn = _adamw(params, grads, state, step=step,
@@ -379,7 +360,7 @@ def train_reference(m: dict, seed: int, batches, optim: dict,
             if step == 1:
                 grad1 = _to_numpy(gn)
         del state
-        p0 = reference_params(seed, m)
+        p0 = reference_params(arch, seed, m)
         delta = _to_numpy(jax.jit(lambda a, b: norms(
             jax.tree.map(jnp.subtract, a, b)))(params, p0))
     return {"losses": losses, "grad1": grad1, "delta": delta}
@@ -390,13 +371,13 @@ def train_reference(m: dict, seed: int, batches, optim: dict,
 # --------------------------------------------------------------------------- #
 
 
-@functools.partial(jax.jit, static_argnames=("m", "precision"))
-def _served_gaps(params, tokens, served, start, n, *, m, precision):
+@functools.partial(jax.jit, static_argnames=("arch", "m", "precision"))
+def _served_gaps(params, tokens, served, start, n, *, arch, m, precision):
     """For one request padded to the context: at the positions that
     produced its served tokens, the gap by which each served token's
     reference logit lies below the reference's best, and (with a lower
     precision) the gap of the token that precision puts first."""
-    lg = logits(dict(m), params, tokens[None], "f32")[0]
+    lg = arch.logits(dict(m), params, tokens[None], make_mm("f32"))[0]
     idx = start + jnp.arange(served.shape[0])
     ref = lg[idx]
     best = ref.max(-1)
@@ -404,19 +385,20 @@ def _served_gaps(params, tokens, served, start, n, *, m, precision):
     valid = jnp.arange(served.shape[0]) < n
     if precision == "f32":
         return jnp.where(valid, gap, 0.0), jnp.zeros_like(gap)
-    low = logits(dict(m), params, tokens[None], precision)[0][idx]
+    low = arch.logits(dict(m), params, tokens[None],
+                      make_mm(precision))[0][idx]
     pick = low.argmax(-1)
     cgap = best - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
     return jnp.where(valid, gap, 0.0), jnp.where(valid, cgap, 0.0)
 
 
-def serve_reference(m: dict, seed: int, requests, context: int,
+def serve_reference(arch, m: dict, seed: int, requests, context: int,
                     control: str | None = None):
     """requests: (prompt, served tokens) pairs. Returns the widest gap of
     a served token below the reference's best and, with ``control``
     ("fp8"), the widest gap of the control's first choice."""
     with jax.default_matmul_precision("highest"):
-        params = reference_params(seed, m)
+        params = reference_params(arch, seed, m)
         mh = _Frozen(m)
         widest, widest_ctl, tokens_compared = 0.0, 0.0, 0
         for prompt, served in requests:
@@ -427,8 +409,8 @@ def serve_reference(m: dict, seed: int, requests, context: int,
             pad[:len(served)] = served
             gap, cgap = _served_gaps(
                 params, jnp.asarray(toks), jnp.asarray(pad),
-                jnp.int32(len(prompt) - 1), jnp.int32(len(served)), m=mh,
-                precision=control or "f32")
+                jnp.int32(len(prompt) - 1), jnp.int32(len(served)),
+                arch=arch, m=mh, precision=control or "f32")
             widest = max(widest, float(gap.max()))
             widest_ctl = max(widest_ctl, float(cgap.max()))
             tokens_compared += len(served)

@@ -4,11 +4,14 @@ asks for, and reading per-leaf norms out of the program's trees."""
 
 from __future__ import annotations
 
+import dataclasses
+import typing
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import _SLOT, layer_of
+from model import _SLOT, layer_of, layer_tables
 
 
 class ConfigArch:
@@ -22,14 +25,28 @@ class ConfigArch:
     def chip_share(self):
         from repro.models.common import ModelConfig, RunConfig
 
-        return ModelConfig(**self._config["model"]), \
-            RunConfig(**self._config["run"])
+        return _dataclass(ModelConfig, self._config["model"]), \
+            _dataclass(RunConfig, self._config["run"])
 
     def config(self):
         return self.chip_share()[0]
 
     def reduced(self):
         return self.chip_share()
+
+
+def _dataclass(cls, values: dict):
+    """``cls(**values)``, each group given as a dict (a "moe" block, say)
+    made into the dataclass that its field's type names."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in values.items():
+        if isinstance(v, dict):
+            kind = next(t for t in typing.get_args(hints[k]) or (hints[k],)
+                        if dataclasses.is_dataclass(t))
+            v = kind(**v)
+        out[k] = v
+    return cls(**out)
 
 
 def register(config: dict) -> str:
@@ -49,23 +66,27 @@ def row_norms(tree, scale: float = 1.0):
             "segments": jax.tree.map(f, tree["segments"])}
 
 
-def leaf_norms(rows, geo, m: dict) -> dict:
+def leaf_norms(rows, geo, arch, m: dict) -> dict:
     """``row_norms`` keyed as the reference keys them: ("io", name) ->
-    [norm], ("layers", name) -> norm per layer."""
+    [norm], ("layers", name) -> norm of each layer that holds the leaf,
+    in layer order."""
     got = jax.device_get(rows)
     out = {("io", n): np.asarray(v, np.float64)
            for n, v in got["io"].items()}
+    holds: dict[str, list] = {}
+    for layer, table in enumerate(layer_tables(arch, m)):
+        for name in table:
+            holds.setdefault(name, []).append(layer)
+    per_layer = {n: np.full(len(ls), np.nan) for n, ls in holds.items()}
     segs = {s.name: s for s in geo.segments}
-    per_layer: dict[str, np.ndarray] = {}
     for seg_name, leaves in got["segments"].items():
         seg = segs[seg_name]
         for full, vals in leaves.items():
             slot, name = _SLOT.fullmatch(full).groups()
-            arr = per_layer.setdefault(name, np.full(m["n_layers"], np.nan))
             for row, v in enumerate(vals):
                 layer = layer_of(geo, seg, row, int(slot))
                 if layer < m["n_layers"]:
-                    arr[layer] = v
+                    per_layer[name][holds[name].index(layer)] = v
     out.update({("layers", n): a for n, a in per_layer.items()})
     return out
 

@@ -71,7 +71,8 @@ def result(cell, out: dict, trace: bool, device: dict, peaks: dict) -> dict:
         summary = trace_reduce.reduce(out["summary"]["events"], kernels)
         rec = dict(out["rec"], trace=summary,
                    trace_calls=out["summary"].get("calls"),
-                   config=cell.config["model"], traffic=cell.traffic,
+                   config=cell.config["model"], arch=cell.arch,
+                   traffic=cell.traffic,
                    peaks=peaks, chips=device["count"], setup=out["setup"],
                    memory_peak_bytes=out["memory_peak_bytes"])
         for name, mod in rd.items():

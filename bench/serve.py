@@ -145,7 +145,8 @@ def setup(cell, seed: int, devices, patch=None, sess=None, warm=None,
     t, m = cell.traffic, cell.config["model"]
     sess = sess or build(cell, data=len(devices) // cell.config["run"]["pp"])
     with span("bench.params"):
-        params = model.program_params(seed, m, sess.geo, sess.param_shapes())
+        params = model.program_params(cell.arch, seed, m, sess.geo,
+                                      sess.param_shapes())
     eng = sess.serve_engine(params)
     drv = OpenLoop(eng)
     calls = Calls(sess, drv.stamp)
@@ -227,10 +228,12 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
         lat["failed"]
     out_tokens = sum(sum(1 for s in x["times"] if s <= t0 + seconds)
                      for x in due)
+    kernels = sess.describe()["kernels"]
     rec = {"window_s": seconds, "calls": window_calls, "tick_s": tick_s,
            "requests": len(due), "failed": failed,
            "output_tokens_in_window": out_tokens,
-           "compiles_in_window": compiles_in_window}
+           "compiles_in_window": compiles_in_window,
+           "kernel_counters": kernels}
     emit("SERVE", requests=len(due), failed=failed, ticks=len(tick_s),
          serve_calls=len(window_calls), output_tokens_in_window=out_tokens,
          ttft_p50_s=percentile(ttft, 50), ttft_p85_s=percentile(ttft, 85),
@@ -240,7 +243,7 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
          generator_late_p50_s=percentile(late, 50),
          generator_late_max_s=max(late) if late else None,
          setup=setup_info, compiles_in_window=compiles_in_window,
-         stats=vars(eng.stats), kernels=sess.describe()["kernels"],
+         stats=vars(eng.stats), kernels=kernels,
          memory_peak_bytes=mem)
 
     # free the engine's pool and weights before the reference runs
@@ -251,7 +254,7 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
     calls.restore()
     del params, eng, drv, calls
     gc.collect()
-    ref = model.serve_reference(m, seed, sample, t["context"])
+    ref = model.serve_reference(cell.arch, m, seed, sample, t["context"])
     return {"setup_s": setup_s, "setup": setup_info, "rec": rec, "sess": sess,
             "memory_peak_bytes": mem, "summary": summary,
             "readings": {"served_gap": ref["gap"]},

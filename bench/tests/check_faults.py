@@ -46,7 +46,7 @@ def _cell(kind: str):
                  prompt={"median": 16, "sigma": 0.5, "min": 8, "max": 40},
                  output={"median": 8, "sigma": 0.5, "min": 6, "max": 20},
                  check={"min_tokens": 60, "max_requests": 8})
-    return common.Cell(real.name, 1, config, t, real.limits,
+    return common.Cell(real.name, 1, config, real.arch, t, real.limits,
                        real.end_to_end, real.per_layer)
 
 
@@ -82,8 +82,9 @@ def test_train_control_is_not_correct():
 
     cell = _cell("train")
     _, out = _correct(cell)
-    got = model.train_reference(cell.config["model"], SEED, out["batches"],
-                                cell.traffic["optim"], precision="fp8")
+    got = model.train_reference(cell.arch, cell.config["model"], SEED,
+                                out["batches"], cell.traffic["optim"],
+                                precision="fp8")
     readings = train.compare(got["losses"], got["grad1"], got["delta"],
                              out["reference"])
     assert any(readings[k] > lim for k, lim in cell.limits.items()), readings
@@ -104,6 +105,7 @@ def test_serve_control_is_not_correct():
 
     cell = _cell("serve")
     _, out = _correct(cell)
-    got = model.serve_reference(cell.config["model"], SEED, out["sample"],
-                                cell.traffic["context"], control="fp8")
+    got = model.serve_reference(cell.arch, cell.config["model"], SEED,
+                                out["sample"], cell.traffic["context"],
+                                control="fp8")
     assert got["control_gap"] > cell.limits["served_gap"], got

@@ -55,16 +55,17 @@ def build(cell, data: int):
                    optim=model.freeze(t["optim"]), data=data)
 
 
-def _step(sess, state: dict, batch):
+def _step(sess, state: dict, batch) -> dict:
     """One train step and AdamW step on ``state`` ("params", "opt"),
     replaced in place: no other reference to the donated trees stays
-    alive while the next step's buffers are allocated."""
+    alive while the next step's buffers are allocated. Returns the train
+    step's ``metrics`` (its counters, on the devices)."""
     with span("bench.train_step"):
         grads, metrics = sess.train_step(state["params"], batch)
     with span("bench.opt_step"):
         state["params"], state["opt"], _ = sess.opt_step(
             state.pop("params"), grads, state.pop("opt"))
-    return metrics["loss_sum"]
+    return metrics
 
 
 def _loop(sess, state: dict, feed, first: int, seconds: float | None,
@@ -72,11 +73,14 @@ def _loop(sess, state: dict, feed, first: int, seconds: float | None,
     """Steps from batch ``first`` on, each next batch put on the devices
     while a step runs; one step in flight, since two steps' gradients do
     not fit beside the state. For ``seconds`` of host time or ``steps``
-    steps; ends when the devices are done."""
+    steps; ends when the devices are done. Also returns the last step's
+    ``metrics``, still on the devices; an earlier step's are dropped
+    before the next step runs, all but its loss."""
     t0 = time.perf_counter()
     nxt, losses, n, ends = feed.device(first), [], 0, [t0]
     while True:
-        loss = _step(sess, state, nxt)
+        metrics = _step(sess, state, nxt)
+        loss = metrics["loss_sum"]
         n += 1
         nxt = feed.device(first + n)
         with span("bench.wait"):
@@ -85,20 +89,21 @@ def _loop(sess, state: dict, feed, first: int, seconds: float | None,
         ends.append(time.perf_counter())
         if (steps is not None and n >= steps) or \
                 (seconds is not None and ends[-1] - t0 >= seconds):
-            break
-    return losses, n, ends[-1] - t0, np.diff(ends)
+            return losses, n, ends[-1] - t0, np.diff(ends), metrics
+        del metrics
 
 
 def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
         t_start: float, clock, devices, patch=None, sess=None) -> dict:
-    """One run of the cell; ``patch(sess)`` may plant a fault, and
+    """One run of the cell; ``patch(sess, arch)`` may plant a fault, and
     ``sess`` reuse a session of an earlier run (tests, calibration)."""
-    t, m = cell.traffic, cell.config["model"]
+    t, m, arch = cell.traffic, cell.config["model"], cell.arch
     sess = sess or build(cell, data=len(devices) // cell.config["run"]["pp"])
     if patch is not None:
-        patch(sess)
+        patch(sess, arch)
     b1 = t["optim"]["b1"]
-    make, key = model.program_maker(m, sess.geo, sess.param_shapes()), \
+    make, key = model.program_maker(arch, m, sess.geo,
+                                    sess.param_shapes()), \
         model.seed_key(seed)
     with span("bench.params"):
         params = make(key)
@@ -114,26 +119,30 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
         lambda x, y: x - y.astype(jnp.float32), master, p0)))
     losses = []
     for k in range(t["check_steps"]):
-        loss = _step(sess, state, feed.device(k))
+        loss = _step(sess, state, feed.device(k))["loss_sum"]
         jax.block_until_ready(state["opt"])
         losses.append(float(loss))
         if k == 0:
-            grad1 = program.leaf_norms(moment(state["opt"]["m"]), sess.geo, m)
+            grad1 = program.leaf_norms(moment(state["opt"]["m"]), sess.geo,
+                                       arch, m)
     p0 = make(key)
     delta = program.leaf_norms(change(state["opt"]["master"], p0),
-                               sess.geo, m)
+                               sess.geo, arch, m)
     del p0
     setup = clock.summary()
     setup_s = time.perf_counter() - t_start
 
     compiles0 = clock.compiles
-    wl, steps, window_s, step_s = _loop(sess, state, feed, t["check_steps"],
-                                        seconds, None)
+    wl, steps, window_s, step_s, last = _loop(
+        sess, state, feed, t["check_steps"], seconds, None)
     tokens = steps * feed.batch * t["seq"]
+    kernels = sess.describe()["kernels"]
     rec = {"window_s": window_s, "steps": steps, "tokens": tokens,
            "train_tokens_per_s": tokens / window_s,
            "compiles_in_window": clock.compiles - compiles0,
-           "failed_steps": sum(not math.isfinite(x) for x in wl)}
+           "failed_steps": sum(not math.isfinite(x) for x in wl),
+           "step_metrics": jax.device_get(last), "kernel_counters": kernels}
+    del last
 
     summary = None
     if trace_dir is not None:
@@ -155,7 +164,7 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
                  "over_1.1x_median": int(
                      (step_s > 1.1 * np.median(step_s)).sum())},
          compiles_in_window=rec["compiles_in_window"],
-         kernels=sess.describe()["kernels"], memory_peak_bytes=mem,
+         kernels=kernels, memory_peak_bytes=mem,
          geometry={"pp": sess.rc.pp, "microbatches": sess.rc.microbatches,
                    "unit": sess.rc.unit_size, "mesh": dict(sess.mesh.shape)})
     state.clear()
@@ -163,7 +172,7 @@ def run(cell, *, seed: int, seconds: float, trace_dir: str | None,
 
     # the reference, on the first steps' batches
     batches = [feed.host(k) for k in range(t["check_steps"])]
-    ref = model.train_reference(m, seed, batches, t["optim"])
+    ref = model.train_reference(arch, m, seed, batches, t["optim"])
     readings = compare(losses, grad1, delta, ref)
     emit("TRAIN_CHECK", losses=losses, reference_losses=ref["losses"],
          worst_grad=program.leaf_gaps(grad1, ref["grad1"])[:3],
