@@ -720,13 +720,15 @@ def segment_train_scan(
             c["metrics"]["aux_sum"] + aux.val.astype(jnp.float32))
         if track_moe and ctx.moe_stats:
             Ls = len(seg.kinds)
-            ml, dr = c["metrics"]["moe_load"], c["metrics"]["moe_dropped"]
-            for pfx_, load, dropped in ctx.moe_stats:
-                j = int(pfx_.split(".", 1)[0][1:])  # "L{j}.ffn" -> j
-                ml = ml.at[stage_id * Ls + j].add(load)
+            mt = c["metrics"]
+            ml, dr, hr = mt["moe_load"], mt["moe_dropped"], mt["moe_rows_held"]
+            for pfx_, load, dropped, held in ctx.moe_stats:
+                row = stage_id * Ls + int(pfx_.split(".", 1)[0][1:])
+                ml = ml.at[row].add(load)              # "L{j}.ffn" -> j
+                hr = hr.at[row].add(held)
                 dr = dr + dropped
-            c["metrics"]["moe_load"] = ml
-            c["metrics"]["moe_dropped"] = dr
+            mt["moe_load"], mt["moe_dropped"], mt["moe_rows_held"] = \
+                ml, dr, hr
         return c
 
     def w_branch(c, row):
@@ -780,13 +782,14 @@ def train_body(params, batch, *, rt, shape_cfg, mbs, vloc,
                 "aux_sum": jnp.zeros((), jnp.float32),
                 "emb_dropped": jnp.zeros((), jnp.int32)}
     if rc.moe_stats and cfg.moe is not None:
-        # per-(stage, stage-layer) expert-load histogram: row
+        # per-(stage, stage-layer) expert-load histogram over every
+        # routed expert, and the (token, k) pairs computed here: row
         # stage_id * len(seg.kinds) + j is global (padded) layer j of
         # stage stage_id; the final psum totals it across ranks.
         seg_m = rt.segs["dec" if cfg.encdec is not None else "main"]
         rows = rt.Pe * seg_m.vpp * len(seg_m.kinds)
-        metrics0["moe_load"] = jnp.zeros((rows, cfg.moe.n_experts),
-                                         jnp.int32)
+        metrics0["moe_load"] = jnp.zeros((rows, cfg.moe.routed), jnp.int32)
+        metrics0["moe_rows_held"] = jnp.zeros((rows,), jnp.int32)
         metrics0["moe_dropped"] = jnp.zeros((), jnp.int32)
 
     if cfg.encdec is None:
@@ -1017,8 +1020,7 @@ def serve_body(params, caches, batch, *, rt, shape_cfg, mbs,
     )
     if track_moe:
         rows_m = Pe * V * len(seg.kinds)
-        carry["moe_load"] = jnp.zeros((rows_m, cfg.moe.n_experts),
-                                      jnp.int32)
+        carry["moe_load"] = jnp.zeros((rows_m, cfg.moe.routed), jnp.int32)
         carry["moe_dropped"] = jnp.zeros((), jnp.int32)
     if want_logits:
         # per-u drain logits land here; vloc path: every data rank
@@ -1062,7 +1064,7 @@ def serve_body(params, caches, batch, *, rt, shape_cfg, mbs,
         if track_moe and ctx.moe_stats:
             Ls = len(seg.kinds)
             ml, dr = c["moe_load"], c["moe_dropped"]
-            for pfx_, load, dropped in ctx.moe_stats:
+            for pfx_, load, dropped, _ in ctx.moe_stats:
                 j = int(pfx_.split(".", 1)[0][1:])
                 ml = ml.at[stage_id * Ls + j].add(load)
                 dr = dr + dropped

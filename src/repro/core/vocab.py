@@ -115,7 +115,8 @@ def _final_norm_fwd(cfg, io_p, h, norm_key="final_norm"):
         hn = (hf - mu) * inv
         y = hn * scale + io_p["final_norm.bias"].astype(jnp.float32)
         return y, (hf, hn, inv, scale)  # layernorm path
-    inv = jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True) + 1e-6)
+    inv = jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True)
+                        + cfg.norm_eps)
     return hf * inv * scale, (hf, hf * inv, inv, scale)
 
 

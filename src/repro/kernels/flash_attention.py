@@ -130,12 +130,12 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, block_q=None,
     decode uses ops.decode_attention instead. ``block_q``/``block_k``
     default to ``fwd_plan``'s choice for the shapes.
 
-    Differentiable through a ``custom_vjp`` whose backward is the vjp of
-    the jnp reference (``ref.attention``): the forward runs the Pallas
-    kernel, the backward recomputes attention in XLA from the saved
-    q/k/v. Each traced backward bumps the ``ref_attention_bwd`` kernel
-    counter, so the reference backward is visible in
-    ``Session.describe()["kernels"]``.
+    Differentiable through a ``custom_vjp`` whose backward is the jnp
+    reference's blocked backward (``ref.attention_bwd``): the forward
+    runs the Pallas kernel, the backward recomputes attention in XLA
+    from the saved q/k/v, a k-block at a time. Each traced backward
+    bumps the ``ref_attention_bwd`` kernel counter, so the reference
+    backward is visible in ``Session.describe()["kernels"]``.
     """
     return _flash_vjp(q, k, v, causal, q_offset, block_q, block_k,
                       interpret)
@@ -154,10 +154,7 @@ def _flash_vjp_fwd(q, k, v, causal, q_offset, block_q, block_k, interpret):
 
 def _flash_vjp_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
     ops._count("ref_attention_bwd")
-    _, vjp = jax.vjp(
-        functools.partial(ref.attention, causal=causal, q_offset=q_offset),
-        *res)
-    return vjp(g)
+    return ref.attention_bwd(*res, g, causal=causal, q_offset=q_offset)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

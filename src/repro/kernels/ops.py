@@ -165,6 +165,20 @@ def slstm_scan(x_gates, *, state=None, return_state=False, impl=None):
     return ref.slstm_scan(x_gates, state=state, return_state=return_state)
 
 
+def expert_gmm(lhs, rhs, group_sizes, *, impl=None):
+    """Grouped product of expert-sorted rows (``kernels/expert_gmm.py``):
+    lhs [m, k] (m a multiple of ``expert_gmm.TM``), rhs [E, k, n],
+    group_sizes [E]; rows past the groups are garbage on the Pallas path
+    and zero on the reference path, so callers mask them."""
+    from repro.kernels import expert_gmm as eg
+
+    if _use_pallas(impl):
+        _count("expert_gmm")
+        return eg.expert_gmm(lhs, rhs, group_sizes, _interpret())
+    _count("ref_expert_gmm")
+    return eg.reference(lhs, rhs, group_sizes)
+
+
 def softmax_xent(h, w_head, labels, *, chunk=8192, mask=None, impl=None):
     if _use_pallas(impl):
         _count("pallas_xent")

@@ -18,6 +18,68 @@ import jax.numpy as jnp
 # --------------------------------------------------------------------------- #
 
 
+def _kv_blocks(k, v, block_k):
+    """k and v in f32, padded to whole blocks of ``block_k`` rows and
+    stacked block first, with each block's index: the scans' ``xs``."""
+    b, sk, g, e = k.shape
+    n_blocks = -(-sk // block_k)
+    pad = n_blocks * block_k - sk
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kf = k.astype(jnp.float32).reshape(b, n_blocks, block_k, g, e)
+    vf = v.astype(jnp.float32).reshape(b, n_blocks, block_k, g, v.shape[-1])
+    return jnp.moveaxis(kf, 1, 0), jnp.moveaxis(vf, 1, 0), jnp.arange(n_blocks)
+
+
+def _block_scores(qf, kb, blk_idx, q_pos, sk, causal):
+    """One k-block's scores ``[b, h, sq, block_k]``, -inf where masked
+    (above the diagonal, or sk padding), and the mask."""
+    sq, h = qf.shape[1], qf.shape[2]
+    block_k = kb.shape[1]
+    k_pos = blk_idx * block_k + jnp.arange(block_k)
+    kb_h = jnp.repeat(kb, h // kb.shape[2], axis=2)  # [b, block_k, h, e]
+    s = jnp.einsum("bqhe,bkhe->bhqk", qf, kb_h.astype(jnp.float32))
+    mask = k_pos[None, :] <= q_pos[..., :, None] if causal else (
+        k_pos[None, :] >= 0
+    ) & jnp.ones((sq, 1), bool)
+    valid = k_pos < sk  # mask out sk padding
+    mask = mask & valid[None, :]
+    # [sq, bk] -> [1, 1, sq, bk]; per-row [b, sq, bk] -> [b, 1, sq, bk]
+    mask = mask[:, None] if mask.ndim == 3 else mask[None, None]
+    return jnp.where(mask, s, -jnp.inf), mask
+
+
+def _online_softmax(qf, blocks, q_pos, sk, causal):
+    """The streaming softmax over k-blocks: each row's running max ``m``,
+    normaliser ``l`` and unnormalised output ``acc`` (f32)."""
+    b, sq, h, _ = qf.shape
+    ev = blocks[1].shape[-1]  # may differ from e (e.g. MLA)
+
+    def body(carry, blk):
+        m, l, acc = carry
+        kb, vb, blk_idx = blk  # kb/vb: [b, block_k, g, e]
+        s, mask = _block_scores(qf, kb, blk_idx, q_pos, sk, causal)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        # guard fully-masked rows
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - m_safe[..., None])
+        p = jnp.where(mask, p, 0.0)
+        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        l_new = l * corr + p.sum(axis=-1)
+        vb_h = jnp.repeat(vb, h // vb.shape[2], axis=2).astype(jnp.float32)
+        acc_new = acc * corr[..., None] + jnp.einsum(
+            "bhqk,bkhe->bhqe", p, vb_h
+        )
+        return (m_new, l_new, acc_new), None
+
+    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, h, sq), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, ev), jnp.float32)
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), blocks)
+    return m, l, acc
+
+
 def attention(
     q: jnp.ndarray,  # [b, sq, h, e]
     k: jnp.ndarray,  # [b, sk, g, e]   g == kv heads, h % g == 0
@@ -35,69 +97,59 @@ def attention(
     (slotted serving, where every slot sits at a different position).
     Accumulation in f32 regardless of input dtype.
     """
-    b, sq, h, e = q.shape
-    _, sk, g, _ = k.shape
-    ev = v.shape[-1]  # may differ from e (e.g. MLA)
-    rep = h // g
+    sq, e = q.shape[1], q.shape[3]
     scale = scale if scale is not None else (1.0 / e ** 0.5)
-
-    # pad sk to a multiple of block_k
-    n_blocks = -(-sk // block_k)
-    pad = n_blocks * block_k - sk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-
     qf = (q * scale).astype(jnp.float32)
-    kf = k.astype(jnp.float32).reshape(b, n_blocks, block_k, g, e)
-    vf = v.astype(jnp.float32).reshape(b, n_blocks, block_k, g, ev)
-
     off = jnp.asarray(q_offset)
     per_row = off.ndim == 1  # [b] vector: per-slot absolute positions
     q_pos = jnp.arange(sq) + (off[:, None] if per_row else off)
-
-    def body(carry, blk):
-        m, l, acc = carry
-        kb, vb, blk_idx = blk  # kb/vb: [b, block_k, g, e]
-        k_pos = blk_idx * block_k + jnp.arange(block_k)
-        # scores: [b, h, sq, block_k]
-        kb_h = jnp.repeat(kb, rep, axis=2)  # [b, block_k, h, e]
-        s = jnp.einsum("bqhe,bkhe->bhqk", qf, kb_h.astype(jnp.float32))
-        mask = k_pos[None, :] <= q_pos[..., :, None] if causal else (
-            k_pos[None, :] >= 0
-        ) & jnp.ones((sq, 1), bool)
-        valid = k_pos < sk  # mask out sk padding
-        mask = mask & valid[None, :]
-        # [sq, bk] -> [1, 1, sq, bk]; per-row [b, sq, bk] -> [b, 1, sq, bk]
-        mask = mask[:, None] if mask.ndim == 3 else mask[None, None]
-        s = jnp.where(mask, s, -jnp.inf)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        # guard fully-masked rows
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[..., None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l_new = l * corr + p.sum(axis=-1)
-        vb_h = jnp.repeat(vb, rep, axis=2).astype(jnp.float32)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhqk,bkhe->bhqe", p, vb_h
-        )
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, h, sq, ev), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body,
-        (m0, l0, acc0),
-        (
-            jnp.moveaxis(kf, 1, 0),
-            jnp.moveaxis(vf, 1, 0),
-            jnp.arange(n_blocks),
-        ),
-    )
+    _, l, acc = _online_softmax(qf, _kv_blocks(k, v, block_k), q_pos,
+                                k.shape[1], causal)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return jnp.einsum("bhqe->bqhe", out).astype(q.dtype)
+
+
+def attention_bwd(q, k, v, do, *, causal=True, q_offset=0, block_k=512):
+    """The gradient of causal or full attention (``attention`` with a
+    static ``q_offset``) by k-blocks, as flash attention's backward
+    takes it: one pass recomputes the output and each row's
+    log-sum-exp; the second takes each block's scores again and returns
+    its dk and dv, accumulating dq. Nothing a block makes outlives it,
+    and nothing but the first pass runs before ``do`` is known.
+    Returns (dq, dk, dv) in the dtypes of (q, k, v)."""
+    b, sq, h, e = q.shape
+    sk, g = k.shape[1], k.shape[2]
+    rep = h // g
+    scale = 1.0 / e ** 0.5
+    blocks = _kv_blocks(k, v, block_k)
+    qf = q.astype(jnp.float32) * scale                 # [b, sq, h, e]
+    q_pos = jnp.arange(sq) + q_offset
+    m, l, acc = _online_softmax(qf, blocks, q_pos, sk, causal)
+    l = jnp.maximum(l, 1e-30)
+    lse = jnp.where(jnp.isfinite(m), m, 0.0) + jnp.log(l)
+    dof = jnp.einsum("bqhe->bhqe", do.astype(jnp.float32))
+    delta = (dof * acc / l[..., None]).sum(-1)         # rowsum(do * o)
+
+    def backward(dq, blk):
+        kb, vb, j = blk
+        s, mask = _block_scores(qf, kb, j, q_pos, sk, causal)
+        p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
+        dv = jnp.einsum("bhqk,bhqe->bkhe", p, dof)
+        dp = jnp.einsum("bhqe,bkhe->bhqk", dof, jnp.repeat(vb, rep, axis=2))
+        ds = p * (dp - delta[..., None])
+        dq = dq + jnp.einsum("bhqk,bkhe->bqhe", ds,
+                             jnp.repeat(kb, rep, axis=2))
+        dk = jnp.einsum("bhqk,bqhe->bkhe", ds, qf)
+        group = lambda a: a.reshape(a.shape[:2] + (g, rep, a.shape[-1])  # noqa
+                                    ).sum(3)
+        return dq, (group(dk), group(dv))
+
+    dq, (dk, dv) = jax.lax.scan(
+        backward, jnp.zeros((b, sq, h, e), jnp.float32), blocks)
+    unblock = lambda a: a.swapaxes(0, 1).reshape(  # noqa: E731
+        (b, -1) + a.shape[3:])[:, :sk]
+    return ((dq * scale).astype(q.dtype), unblock(dk).astype(k.dtype),
+            unblock(dv).astype(v.dtype))
 
 
 def attention_naive(q, k, v, *, causal=True, q_offset=0, scale=None):

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.tape import Tape, TVal
-from repro.kernels import ops
+from repro.kernels import expert_gmm, ops
 from repro.models.common import (
     MLACfg,
     ModelConfig,
@@ -51,8 +51,9 @@ class LayerCtx:
     #                                  contiguous per-row cache layout
     page_size: int = 0               # tokens per page when paged
     moe_stats: Any = None            # None (off) | list collector: apply_moe
-    #                                  appends (pfx, load[E], dropped) per
-    #                                  layer when set (RunConfig.moe_stats)
+    #                                  appends (pfx, load[routed], dropped,
+    #                                  pairs held) per layer when set
+    #                                  (RunConfig.moe_stats)
 
 
 # --------------------------------------------------------------------------- #
@@ -70,6 +71,13 @@ def norm_specs(cfg: ModelConfig, pfx: str) -> dict[str, ParamSpec]:
     return {f"{pfx}.scale": ParamSpec((d,), "ones", fsdp_dim=0)}
 
 
+def rms_norm(v, scale, eps):
+    """RMSNorm in f32, the result in ``v``'s dtype."""
+    vf = v.astype(jnp.float32)
+    y = vf * jax.lax.rsqrt(jnp.mean(vf * vf, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(v.dtype)
+
+
 def apply_norm(t: Tape, cfg: ModelConfig, pfx: str, x: TVal) -> TVal:
     if cfg.norm == "layernorm":
         def ln(scale, bias, v):
@@ -80,25 +88,18 @@ def apply_norm(t: Tape, cfg: ModelConfig, pfx: str, x: TVal) -> TVal:
             return (y * scale + bias).astype(v.dtype)
 
         return t.prim(ln, x, pnames=(f"{pfx}.scale", f"{pfx}.bias"))
-
-    def rms(scale, v):
-        vf = v.astype(jnp.float32)
-        y = vf * jax.lax.rsqrt(jnp.mean(vf * vf, axis=-1, keepdims=True) + 1e-6)
-        return (y * scale).astype(v.dtype)
-
-    return t.prim(rms, x, pnames=(f"{pfx}.scale",))
+    return _rms_sub(t, cfg, f"{pfx}.scale", x)
 
 
 def norm_fwd(cfg, params, pfx, x):
     """Pure fwd (decode path)."""
-    xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
+        xf = x.astype(jnp.float32)
         mu = xf.mean(axis=-1, keepdims=True)
         var = ((xf - mu) ** 2).mean(axis=-1, keepdims=True)
         y = (xf - mu) * jax.lax.rsqrt(var + 1e-5)
         return (y * params[f"{pfx}.scale"] + params[f"{pfx}.bias"]).astype(x.dtype)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
-    return (y * params[f"{pfx}.scale"]).astype(x.dtype)
+    return rms_norm(x, params[f"{pfx}.scale"], cfg.norm_eps)
 
 
 # --------------------------------------------------------------------------- #
@@ -185,11 +186,16 @@ def cross_attn_decode(ctx, params, pfx, x, memory):
 def mla_specs(cfg: ModelConfig, pfx: str):
     m: MLACfg = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
-    return {
-        f"{pfx}.wdq": ParamSpec((d, m.q_lora), fsdp_dim=0),
-        f"{pfx}.qnorm.scale": ParamSpec((m.q_lora,), "ones"),
-        f"{pfx}.wuq": ParamSpec((m.q_lora, h, m.qk_nope + m.rope_dims),
-                                fsdp_dim=0),
+    e = m.qk_nope + m.rope_dims
+    if m.q_lora:
+        sp = {
+            f"{pfx}.wdq": ParamSpec((d, m.q_lora), fsdp_dim=0),
+            f"{pfx}.qnorm.scale": ParamSpec((m.q_lora,), "ones"),
+            f"{pfx}.wuq": ParamSpec((m.q_lora, h, e), fsdp_dim=0),
+        }
+    else:
+        sp = {f"{pfx}.wq": ParamSpec((d, h, e), fsdp_dim=0)}
+    return sp | {
         f"{pfx}.wdkv": ParamSpec((d, m.kv_lora + m.rope_dims), fsdp_dim=0),
         f"{pfx}.kvnorm.scale": ParamSpec((m.kv_lora,), "ones"),
         f"{pfx}.wuk": ParamSpec((m.kv_lora, h, m.qk_nope), fsdp_dim=0),
@@ -198,23 +204,31 @@ def mla_specs(cfg: ModelConfig, pfx: str):
     }
 
 
+def _mla_q(cfg, params, pfx, x):
+    """q [b, s, h, qk_nope + rope] of the decode and cached paths."""
+    if not cfg.mla.q_lora:
+        return jnp.einsum("bsd,dhe->bshe", x, params[f"{pfx}.wq"])
+    cq = jnp.einsum("bsd,dr->bsr", x, params[f"{pfx}.wdq"])
+    cq = rms_norm(cq, params[f"{pfx}.qnorm.scale"], cfg.norm_eps)
+    return jnp.einsum("bsr,rhe->bshe", cq, params[f"{pfx}.wuq"])
+
+
 def apply_mla(t: Tape, ctx: LayerCtx, pfx: str, x: TVal) -> TVal:
+    """Attention scores are scaled by 1/sqrt(qk_nope + rope), the width
+    of q and k, as ``ops.attention`` scales them."""
     cfg = ctx.cfg
     m: MLACfg = cfg.mla
-    cq = t.dense(x, f"{pfx}.wdq", "bsd,dr->bsr")
-    cq = _rms_sub(t, f"{pfx}.qnorm.scale", cq)
-    q = t.dense(cq, f"{pfx}.wuq", "bsr,rhe->bshe")  # e = qk_nope + rope
+    if m.q_lora:
+        cq = t.dense(x, f"{pfx}.wdq", "bsd,dr->bsr")
+        cq = _rms_sub(t, cfg, f"{pfx}.qnorm.scale", cq)
+        q = t.dense(cq, f"{pfx}.wuq", "bsr,rhe->bshe")  # e = qk_nope + rope
+    else:
+        q = t.dense(x, f"{pfx}.wq", "bsd,dhe->bshe")
     ckv = t.dense(x, f"{pfx}.wdkv", "bsd,dc->bsc")  # c = kv_lora + rope
 
     def split_norm(scale, c):
-        c_kv = c[..., : m.kv_lora]
-        k_rope = c[..., m.kv_lora:]
-        cf = c_kv.astype(jnp.float32)
-        c_kv = (
-            cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True) + 1e-6)
-            * scale
-        ).astype(c.dtype)
-        return c_kv, k_rope
+        return rms_norm(c[..., : m.kv_lora], scale, cfg.norm_eps), \
+            c[..., m.kv_lora:]
 
     c_kv, k_rope = t.prim(
         split_norm, ckv, pnames=(f"{pfx}.kvnorm.scale",), n_out=2
@@ -232,7 +246,6 @@ def apply_mla(t: Tape, ctx: LayerCtx, pfx: str, x: TVal) -> TVal:
         )
         qf = jnp.concatenate([q_nope, q_rope], axis=-1)
         kf = jnp.concatenate([knope, k_rope_b], axis=-1)
-        scale = 1.0 / (m.qk_nope + m.rope_dims) ** 0.5
         return ops.attention(
             qf, kf, val, causal=ctx.causal, block_k=ctx.rc.attn_block_k,
             impl=ctx.rc.kernel_impl,
@@ -246,20 +259,15 @@ def mla_decode(ctx, params, pfx, x, cache, pos):
     """Cache holds the *compressed* ckv [b, S, kv_lora + rope_dims]."""
     cfg = ctx.cfg
     m: MLACfg = cfg.mla
-    cq = jnp.einsum("bsd,dr->bsr", x, params[f"{pfx}.wdq"])
-    cqf = cq.astype(jnp.float32)
-    cq = (cqf * jax.lax.rsqrt(jnp.mean(cqf * cqf, -1, keepdims=True) + 1e-6)
-          * params[f"{pfx}.qnorm.scale"]).astype(x.dtype)
-    q = jnp.einsum("bsr,rhe->bshe", cq, params[f"{pfx}.wuq"])
+    q = _mla_q(cfg, params, pfx, x)
     ckv = jnp.einsum("bsd,dc->bsc", x, params[f"{pfx}.wdkv"])
     cache_new = jax.lax.dynamic_update_slice(
         cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, pos, 0)
     )
     full = cache_new  # [b, S, c]
     c_kv, k_rope = full[..., : m.kv_lora], full[..., m.kv_lora:]
-    cf = c_kv.astype(jnp.float32)
-    c_kv = (cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True) + 1e-6)
-            * params[f"{pfx}.kvnorm.scale"]).astype(x.dtype)
+    c_kv = rms_norm(c_kv.astype(jnp.float32), params[f"{pfx}.kvnorm.scale"],
+                    cfg.norm_eps).astype(x.dtype)
     k_nope = jnp.einsum("bsc,che->bshe", c_kv, params[f"{pfx}.wuk"])
     v = jnp.einsum("bsc,che->bshe", c_kv, params[f"{pfx}.wuv"])
     cos_q, sin_q = ctx.rope[m.rope_dims]          # [1, rope/2] current pos
@@ -270,20 +278,15 @@ def mla_decode(ctx, params, pfx, x, cache, pos):
     k_rope = jnp.broadcast_to(k_rope, k_nope.shape[:3] + (m.rope_dims,))
     qf = jnp.concatenate([q_nope, q_rope], -1)
     kf = jnp.concatenate([k_nope, k_rope], -1)
-    scale = 1.0 / (m.qk_nope + m.rope_dims) ** 0.5
     o, _ = ops.decode_attention(qf, kf, v, cache_len=pos + 1,
                                 impl=ctx.rc.kernel_impl)
     y = jnp.einsum("bshe,hed->bsd", o, params[f"{pfx}.wo"])
     return y, {"ckv": cache_new}
 
 
-def _rms_sub(t: Tape, scale_name: str, x: TVal) -> TVal:
-    def rms(scale, v):
-        vf = v.astype(jnp.float32)
-        y = vf * jax.lax.rsqrt(jnp.mean(vf * vf, -1, keepdims=True) + 1e-6)
-        return (y * scale).astype(v.dtype)
-
-    return t.prim(rms, x, pnames=(scale_name,))
+def _rms_sub(t: Tape, cfg: ModelConfig, scale_name: str, x: TVal) -> TVal:
+    return t.prim(lambda scale, v: rms_norm(v, scale, cfg.norm_eps), x,
+                  pnames=(scale_name,))
 
 
 # --------------------------------------------------------------------------- #
@@ -326,7 +329,7 @@ def ffn_fwd(ctx, params, pfx, x):
 
 
 # --------------------------------------------------------------------------- #
-# MoE (shared + routed top-k, capacity-based dispatch)
+# MoE: shared experts + routed top-k over the experts held here
 # --------------------------------------------------------------------------- #
 
 
@@ -334,11 +337,13 @@ def moe_specs(cfg: ModelConfig, pfx: str):
     mo = cfg.moe
     d, fe = cfg.d_model, mo.d_ff_expert
     sp = {
-        f"{pfx}.router": ParamSpec((d, mo.n_experts), fsdp_dim=0, scale=0.1),
+        f"{pfx}.router": ParamSpec((d, mo.routed), fsdp_dim=0, scale=0.1),
         f"{pfx}.e_wg": ParamSpec((mo.n_experts, d, fe), fsdp_dim=2, ep=True),
         f"{pfx}.e_wu": ParamSpec((mo.n_experts, d, fe), fsdp_dim=2, ep=True),
         f"{pfx}.e_wd": ParamSpec((mo.n_experts, fe, d), fsdp_dim=1, ep=True),
     }
+    if mo.scoring == "sigmoid":
+        sp[f"{pfx}.router_bias"] = ParamSpec((mo.routed,), "zeros")
     if mo.n_shared:
         fs = mo.d_ff_shared or fe * mo.n_shared
         sp.update({
@@ -354,96 +359,90 @@ def _capacity(n_tok: int, mo) -> int:
     return max(8, -(-c // 8) * 8)  # round up to 8
 
 
+def route(mo, lgf, bias=None):
+    """The gate over all ``mo.routed`` experts: f32 logits [n, routed]
+    -> (weights [n, K] f32, experts [n, K], balance term).
+
+    softmax: top-k of the softmax, renormalised; Switch balance term.
+    sigmoid (DeepSeek-V3 ``noaux_tc``): top-k of sigmoid + ``bias``,
+    which chooses and never weighs (no gradient reaches it); the chosen
+    sigmoid scores renormalised; no balance term. Both scale the weights
+    by ``mo.routed_scale``."""
+    K = mo.top_k
+    if mo.scoring == "sigmoid":
+        sc = jax.nn.sigmoid(lgf)
+        _, topi = jax.lax.top_k(
+            sc + jax.lax.stop_gradient(bias.astype(jnp.float32)), K)
+        topw = jnp.take_along_axis(sc, topi, -1)
+        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+        return topw * mo.routed_scale, topi, jnp.zeros((), jnp.float32)
+    probs = jax.nn.softmax(lgf, axis=-1)
+    topw, topi = jax.lax.top_k(probs, K)          # [n, K]
+    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    # Switch-style balance term over every routed expert
+    first = jax.nn.one_hot(topi[:, 0], mo.routed, dtype=jnp.float32)
+    aux = (first.mean(axis=0) * probs.mean(axis=0)).sum() * mo.routed
+    return topw * mo.routed_scale, topi, aux
+
+
 def apply_moe(t: Tape, ctx: LayerCtx, pfx: str, x: TVal) -> tuple[TVal, TVal]:
-    """Returns (y, aux_loss)."""
+    """Returns (y, aux_loss).
+
+    The router scores every routed expert; this layer computes the part
+    of the result that the experts it holds give (``MoECfg.n_experts``
+    from ``held_offset`` on), plus the shared experts, and leaves out
+    what experts held elsewhere would add. Without an expert-parallel
+    axis the held pairs run dropless through the grouped expert product;
+    under ``moe_mode="ep"`` (every routed expert in the layer) they go
+    through capacity buffers and ``all_to_all``."""
     cfg, mo = ctx.cfg, ctx.cfg.moe
     b, s, d = x.shape
-    n = b * s
-    cap = _capacity(n, mo)
-    E, K = mo.n_experts, mo.top_k
-
-    logits = t.dense(x, f"{pfx}.router", "bsd,de->bse")
+    n, K, R = b * s, mo.top_k, mo.routed
 
     # Routing (indices exit the tape as closure captures; weights stay on it).
     holder = {}
 
-    def route(lg):
-        lgf = lg.reshape(n, E).astype(jnp.float32)
-        probs = jax.nn.softmax(lgf, axis=-1)
-        topw, topi = jax.lax.top_k(probs, K)          # [n, K]
-        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
-        # position of each (token, k) within its expert
-        onehot = jax.nn.one_hot(topi, E, dtype=jnp.int32)  # [n, K, E]
-        flat_oh = onehot.reshape(n * K, E)
-        pos = jnp.cumsum(flat_oh, axis=0) - flat_oh         # rank within expert
-        slot = (pos * flat_oh).sum(-1).reshape(n, K)        # [n, K]
-        keep = slot < cap
-        # aux load-balance loss (Switch-style)
-        frac_tok = jnp.mean(onehot[:, 0].astype(jnp.float32), axis=0)
-        frac_prob = probs.mean(axis=0)
-        aux = (frac_tok * frac_prob).sum() * E
+    def gate(lgf, bias):
+        topw, topi, aux = route(mo, lgf, bias)
         holder["topi"] = topi
-        holder["slot"] = jnp.where(keep, slot, cap)  # cap = drop slot
         if ctx.moe_stats is not None:
             # dispatch observability (RunConfig.moe_stats): routed
-            # assignment count per expert and capacity-dropped count —
-            # integers exiting the tape as closure captures like topi.
-            # Slotted serving pads inactive rows; mask them out so the
-            # histogram counts only live requests' tokens.
+            # assignment count per expert over all routed experts, and
+            # the (token, k) pairs whose expert is held here. Slotted
+            # serving pads inactive rows; mask them out so the histogram
+            # counts only live requests' tokens.
             if ctx.slot_mask is not None:
                 live = jnp.repeat(ctx.slot_mask.astype(jnp.int32), s * K)
             else:
                 live = jnp.ones((n * K,), jnp.int32)
-            holder["load"] = (flat_oh * live[:, None]).sum(0)
-            holder["dropped"] = (
-                (~keep).reshape(-1).astype(jnp.int32) * live).sum()
+            flat = topi.reshape(-1)
+            held = (flat >= mo.held_offset) & (
+                flat < mo.held_offset + mo.n_experts)
+            holder["load"] = jnp.zeros((R,), jnp.int32).at[flat].add(live)
+            holder["held"] = (held.astype(jnp.int32) * live).sum()
         return topw, aux
 
-    topw, aux = t.prim(route, logits, n_out=2)
+    if mo.scoring == "sigmoid":
+        def sigmoid_gate(w, bias, xv):
+            # the published gate computes its logits in f32
+            lgf = jnp.einsum("nd,de->ne", xv.reshape(n, d).astype(jnp.float32),
+                             w.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            return gate(lgf, bias)
 
-    def dispatch(xv):
-        xf = xv.reshape(n, d)
-        buf = jnp.zeros((E, cap + 1, d), xv.dtype)
-        ti = holder["topi"].reshape(-1)
-        sl = holder["slot"].reshape(-1)
-        xk = jnp.repeat(xf, K, axis=0)
-        return buf.at[ti, sl].add(xk)[:, :cap]
+        topw, aux = t.prim(sigmoid_gate, x, n_out=2,
+                           pnames=(f"{pfx}.router", f"{pfx}.router_bias"))
+    else:
+        logits = t.dense(x, f"{pfx}.router", "bsd,de->bse")
+        topw, aux = t.prim(
+            lambda lg: gate(lg.reshape(n, R).astype(jnp.float32), None),
+            logits, n_out=2)
 
-    xe = t.prim(dispatch, x)  # [E, cap, d]
-
-    if ctx.ep_axis is not None:
-        # all_to_all: split experts over the data axis, concat capacity.
-        ax = ctx.ep_axis
-
-        def a2a_fwd(v):
-            return jax.lax.all_to_all(v, ax, split_axis=0, concat_axis=1,
-                                      tiled=True)
-
-        xe = t.prim(a2a_fwd, xe)  # [E/D, cap*D, d]
-
-    g = t.dense(xe, f"{pfx}.e_wg", "ecd,edf->ecf")
-    u = t.dense(xe, f"{pfx}.e_wu", "ecd,edf->ecf")
-    hh = t.prim(lambda a, b2: jax.nn.silu(a) * b2, g, u)
-    ye = t.dense(hh, f"{pfx}.e_wd", "ecf,efd->ecd")
-
-    if ctx.ep_axis is not None:
-        ax = ctx.ep_axis
-
-        def a2a_bwd(v):
-            return jax.lax.all_to_all(v, ax, split_axis=1, concat_axis=0,
-                                      tiled=True)
-
-        ye = t.prim(a2a_bwd, ye)
-
-    def combine(yv, wv):
-        ti = holder["topi"]            # [n, K]
-        sl = holder["slot"]            # [n, K] (cap = dropped)
-        ypad = jnp.pad(yv, ((0, 0), (0, 1), (0, 0)))  # drop slot reads zeros
-        gathered = ypad[ti, sl]        # [n, K, d]
-        out = (gathered * wv[..., None].astype(yv.dtype)).sum(axis=1)
-        return out.reshape(b, s, d)
-
-    y = t.prim(combine, ye, topw)
+    if ctx.ep_axis is None:
+        y = _grouped_experts(t, ctx, pfx, x, topw, holder)
+        holder["dropped"] = jnp.zeros((), jnp.int32)
+    else:
+        y = _capacity_experts(t, ctx, pfx, x, topw, holder)
 
     if mo.n_shared:
         g2 = t.dense(x, f"{pfx}.s_wg", "bsd,df->bsf")
@@ -452,8 +451,103 @@ def apply_moe(t: Tape, ctx: LayerCtx, pfx: str, x: TVal) -> tuple[TVal, TVal]:
         y2 = t.dense(h2, f"{pfx}.s_wd", "bsf,fd->bsd")
         y = t.add(y, y2)
     if ctx.moe_stats is not None:
-        ctx.moe_stats.append((pfx, holder["load"], holder["dropped"]))
+        ctx.moe_stats.append((pfx, holder["load"], holder["dropped"],
+                              holder["held"]))
     return y, aux
+
+
+def _grouped_experts(t: Tape, ctx: LayerCtx, pfx: str, x: TVal, topw: TVal,
+                     holder: dict) -> TVal:
+    """The held experts' part, dropless: the (token, k) pairs whose
+    expert is held, sorted by expert, through one grouped product per
+    projection (``ops.expert_gmm``), padded to the kernel's row tile.
+    The expert weights' gradients are taken in B (the grouped dW needs
+    the group sizes, which the W stash does not carry)."""
+    mo = ctx.cfg.moe
+    b, s, d = x.shape
+    n, K, E = b * s, mo.top_k, mo.n_experts
+    impl = ctx.rc.kernel_impl
+    rows = expert_gmm.rows(n * min(K, E))   # the most pairs held here
+
+    def experts(wg, wu, wd, xv, wv):
+        local = holder["topi"].reshape(-1) - mo.held_offset     # [n K]
+        held = (local >= 0) & (local < E)
+        key = jnp.where(held, local, E)
+        order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+        pick = order[:rows] if rows <= n * K else jnp.pad(
+            order, (0, rows - n * K))
+        valid = jnp.arange(rows) < sizes.sum()
+        # rows past the groups are masked with selects: the kernel leaves
+        # them unwritten, forward and backward
+        xs = jnp.where(valid[:, None], xv.reshape(n, d)[pick // K], 0)
+        g = ops.expert_gmm(xs, wg, sizes, impl=impl)
+        u = ops.expert_gmm(xs, wu, sizes, impl=impl)
+        ye = ops.expert_gmm(jax.nn.silu(g) * u, wd, sizes, impl=impl)
+        rank = jnp.zeros((n * K,), jnp.int32).at[order].set(
+            jnp.arange(n * K, dtype=jnp.int32))
+        yp = ye[jnp.minimum(rank, rows - 1)].astype(jnp.float32)
+        yp = jnp.where(held[:, None], yp, 0.0).reshape(n, K, d)
+        out = (yp * wv[..., None]).sum(axis=1)
+        return out.astype(xv.dtype).reshape(b, s, d)
+
+    # rematerialised in its backward, so that no layer's buffers (sized
+    # for every pair a token can send here) outlive its own backward
+    return t.prim(jax.checkpoint(experts), x, topw,
+                  pnames=(f"{pfx}.e_wg", f"{pfx}.e_wu", f"{pfx}.e_wd"))
+
+
+def _capacity_experts(t: Tape, ctx: LayerCtx, pfx: str, x: TVal,
+                      topw: TVal, holder: dict) -> TVal:
+    """Every routed expert's part through [E, cap, d] buffers split over
+    the expert-parallel axis by ``all_to_all``; pairs past an expert's
+    capacity are dropped (counted in ``holder["dropped"]``)."""
+    mo = ctx.cfg.moe
+    b, s, d = x.shape
+    n, K, E = b * s, mo.top_k, mo.n_experts
+    if mo.routed != E:
+        raise ValueError("moe_mode='ep' holds every routed expert in the "
+                         f"layer; this layer holds {E} of {mo.routed}")
+    cap = _capacity(n, mo)
+    topi = holder["topi"]
+    # position of each (token, k) within its expert
+    flat_oh = jax.nn.one_hot(topi, E, dtype=jnp.int32).reshape(n * K, E)
+    pos = jnp.cumsum(flat_oh, axis=0) - flat_oh         # rank within expert
+    slot = (pos * flat_oh).sum(-1).reshape(n, K)        # [n, K]
+    keep = slot < cap
+    slot = jnp.where(keep, slot, cap)                   # cap = drop slot
+    if ctx.moe_stats is not None:
+        live = (jnp.ones((n * K,), jnp.int32) if ctx.slot_mask is None else
+                jnp.repeat(ctx.slot_mask.astype(jnp.int32), s * K))
+        holder["dropped"] = ((~keep).reshape(-1).astype(jnp.int32)
+                             * live).sum()
+
+    def dispatch(xv):
+        xf = xv.reshape(n, d)
+        buf = jnp.zeros((E, cap + 1, d), xv.dtype)
+        xk = jnp.repeat(xf, K, axis=0)
+        return buf.at[topi.reshape(-1), slot.reshape(-1)].add(xk)[:, :cap]
+
+    xe = t.prim(dispatch, x)  # [E, cap, d]
+    ax = ctx.ep_axis
+
+    # all_to_all: split experts over the data axis, concat capacity.
+    xe = t.prim(lambda v: jax.lax.all_to_all(v, ax, split_axis=0,
+                                             concat_axis=1, tiled=True), xe)
+    g = t.dense(xe, f"{pfx}.e_wg", "ecd,edf->ecf")     # [E/D, cap*D, ...]
+    u = t.dense(xe, f"{pfx}.e_wu", "ecd,edf->ecf")
+    hh = t.prim(lambda a, b2: jax.nn.silu(a) * b2, g, u)
+    ye = t.dense(hh, f"{pfx}.e_wd", "ecf,efd->ecd")
+    ye = t.prim(lambda v: jax.lax.all_to_all(v, ax, split_axis=1,
+                                             concat_axis=0, tiled=True), ye)
+
+    def combine(yv, wv):
+        ypad = jnp.pad(yv, ((0, 0), (0, 1), (0, 0)))  # drop slot reads zeros
+        gathered = ypad[topi, slot]    # [n, K, d]
+        out = (gathered * wv[..., None].astype(yv.dtype)).sum(axis=1)
+        return out.reshape(b, s, d)
+
+    return t.prim(combine, ye, topw)
 
 
 def moe_fwd(ctx, params, pfx, x):
@@ -875,11 +969,7 @@ def mla_cached(ctx, params, pfx, x, cache, pos):
     cfg = ctx.cfg
     m: MLACfg = cfg.mla
     b, s, d = x.shape
-    cq = jnp.einsum("bsd,dr->bsr", x, params[f"{pfx}.wdq"])
-    cqf = cq.astype(jnp.float32)
-    cq = (cqf * jax.lax.rsqrt(jnp.mean(cqf * cqf, -1, keepdims=True) + 1e-6)
-          * params[f"{pfx}.qnorm.scale"]).astype(x.dtype)
-    q = jnp.einsum("bsr,rhe->bshe", cq, params[f"{pfx}.wuq"])
+    q = _mla_q(cfg, params, pfx, x)
     ckv = jnp.einsum("bsd,dc->bsc", x, params[f"{pfx}.wdkv"])
     ckv_sc = None
     if ctx.page_tables is not None:  # paged latent cache
@@ -901,9 +991,8 @@ def mla_cached(ctx, params, pfx, x, cache, pos):
             cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, pos, 0))
         full = cache_new
     c_kv, k_rope = full[..., : m.kv_lora], full[..., m.kv_lora:]
-    cf = c_kv.astype(jnp.float32)
-    c_kv = (cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True) + 1e-6)
-            * params[f"{pfx}.kvnorm.scale"]).astype(x.dtype)
+    c_kv = rms_norm(c_kv.astype(jnp.float32), params[f"{pfx}.kvnorm.scale"],
+                    cfg.norm_eps).astype(x.dtype)
     k_nope = jnp.einsum("bsc,che->bshe", c_kv, params[f"{pfx}.wuk"])
     vv = jnp.einsum("bsc,che->bshe", c_kv, params[f"{pfx}.wuv"])
     cos_q, sin_q = _rope_slice(ctx, m.rope_dims, pos, s)

@@ -16,21 +16,33 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int
+    n_experts: int                # experts held here: [held_offset,
+                                  # held_offset + n_experts) of n_routed
     top_k: int
     d_ff_expert: int
     n_shared: int = 0
     d_ff_shared: int = 0          # d_ff of the shared expert(s)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # all_to_all buffers (moe_mode "ep")
+                                   # and serving's MoECapacity admission
     every: int = 1                # MoE FFN every N layers (else dense FFN)
     offset: int = 0               # which residue (mod every) gets MoE
     first_dense: int = 0          # first N layers use a dense FFN instead
     router_aux_weight: float = 0.01
+    scoring: str = "softmax"      # softmax | sigmoid (DeepSeek-V3 gate:
+                                  # f32 logits, a selection-only bias
+                                  # "router_bias", no balance term)
+    routed_scale: float = 1.0     # routed weights' factor after top-k
+    n_routed: int = 0             # the router's width; 0 -> n_experts
+    held_offset: int = 0          # first routed expert held here
+
+    @property
+    def routed(self) -> int:
+        return self.n_routed or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLACfg:
-    q_lora: int = 1536
+    q_lora: int = 1536            # 0: q projected directly ("wq")
     kv_lora: int = 512
     rope_dims: int = 64
     v_head: int = 128
@@ -81,6 +93,7 @@ class ModelConfig:
     frontend: str | None = None  # "audio" | "vision" (stubbed embeddings)
     mtp: bool = False            # DeepSeek multi-token-prediction aux head
     max_seq: int = 131_072
+    norm_eps: float = 1e-6       # every RMSNorm's epsilon
 
     @property
     def head_dim(self) -> int:
@@ -154,8 +167,9 @@ class RunConfig:
                                  # a2a-aware cost model before any build)
     moe_stats: bool = False      # collect per-layer expert-load histograms
                                  # + capacity-drop counters (train metrics
-                                 # "moe_load"/"moe_dropped"; serve steps
-                                 # return an extra trailing stats dict)
+                                 # "moe_load"/"moe_dropped"/"moe_rows_held";
+                                 # serve steps return an extra trailing
+                                 # stats dict)
     remat: bool = True
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"

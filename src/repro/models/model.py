@@ -387,7 +387,8 @@ def head_loss(params, cfg, rc, h, labels, mask=None):
     d = cfg.d_model
     scale = params["final_norm.scale"]
     hf = h.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True) + 1e-6)
+    rms = jax.lax.rsqrt(jnp.mean(hf * hf, -1, keepdims=True)
+                        + cfg.norm_eps)
     hn = hf * rms * scale
     w = params["embed.table"].T if cfg.tie_embeddings else params["head.w"]
     loss, (dhn, dw) = ops.softmax_xent(
@@ -526,7 +527,7 @@ def reference_logits(cfg, rc, params, tokens, enc_tokens=None,
             + io["final_norm.bias"]
     else:
         hn = xf * jax.lax.rsqrt(
-            jnp.mean(xf * xf, -1, keepdims=True) + 1e-6) * scale
+            jnp.mean(xf * xf, -1, keepdims=True) + cfg.norm_eps) * scale
     w = io["embed.table"].T if cfg.tie_embeddings else io["head.w"]
     logits = hn.astype(dtype) @ w
     if return_hidden:
@@ -669,8 +670,8 @@ def mtp_hidden(cfg, rc, io_params, h, emb_next, ep_axis=None):
 
     def rms(v):
         vf = v.astype(jnp.float32)
-        return (vf * jax.lax.rsqrt(
-            jnp.mean(vf * vf, -1, keepdims=True) + 1e-6)).astype(v.dtype)
+        return (vf * jax.lax.rsqrt(jnp.mean(vf * vf, -1, keepdims=True)
+                                   + cfg.norm_eps)).astype(v.dtype)
 
     cat = jnp.concatenate([rms(h), rms(emb_next)], axis=-1)
     x = jnp.einsum("bse,ed->bsd", cat, io_params["mtp.proj"])
@@ -701,7 +702,7 @@ def mtp_reference_loss(cfg, rc, io_params, h, tokens, labels):
     scale = io_params["mtp.norm.scale"]
     hf = hm.astype(jnp.float32)
     hn = hf * jax.lax.rsqrt(
-        jnp.mean(hf * hf, -1, keepdims=True) + 1e-6) * scale
+        jnp.mean(hf * hf, -1, keepdims=True) + cfg.norm_eps) * scale
     w = (io_params["embed.table"].T if cfg.tie_embeddings
          else io_params["head.w"])
     logits = hn @ w.astype(jnp.float32)

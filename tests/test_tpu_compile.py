@@ -1,5 +1,7 @@
 """The Pallas kernels of the GPT path compile for a TPU v5e at gpt_paper
-1.5B widths (d_model 2304, 24 heads of 96, vocab 50304, seq 1024).
+1.5B widths (d_model 2304, 24 heads of 96, vocab 50304, seq 1024), and
+those of the Moonlight-16B-A3B cell at its widths (latent attention at
+16 heads of 192 / 128 over 4096 rows; 8 held experts of 2048 x 1408).
 
 Nothing runs: each kernel is lowered and compiled for a *described* v5e
 chip (``jax.experimental.topologies``), which raises what the chip's
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import expert_gmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_xent import softmax_xent
 from repro.kernels.paged_attention import (
@@ -63,8 +66,9 @@ def _compile(fn, chip, *shapes):
     (((1, SEQ, H, E), (1, SEQ, H, E), (1, SEQ, H, E)), True),
     (((1, SEQ, 32, 64), (1, SEQ, 8, 64), (1, SEQ, 8, 64)), True),  # GQA
     (((1, SEQ, 16, 192), (1, SEQ, 16, 192), (1, SEQ, 16, 128)), True),  # MLA
+    (((1, 4096, 16, 192), (1, 4096, 16, 192), (1, 4096, 16, 128)), True),
     (((1, 448, 20, 64), (1, 1500, 20, 64), (1, 1500, 20, 64)), False),
-], ids=["gpt", "gqa", "mla", "whisper_cross"])
+], ids=["gpt", "gqa", "mla", "mla_4k", "whisper_cross"])
 def test_flash_attention_fwd_and_custom_vjp_bwd(chip, shape, causal):
     qkv = [(s, jnp.bfloat16) for s in shape]
 
@@ -74,6 +78,19 @@ def test_flash_attention_fwd_and_custom_vjp_bwd(chip, shape, causal):
         return out, vjp(out)
 
     _compile(fwd_bwd, chip, *qkv)
+
+
+def test_expert_gmm_fwd_and_bwd(chip):
+    """The grouped expert product, forward, dx and dW, over a micro-batch
+    of 4096 tokens x 6 pairs, 8 held experts of 2048 x 1408."""
+    def fwd_bwd(lhs, rhs, sizes):
+        out, vjp = jax.vjp(
+            lambda a, b: expert_gmm.expert_gmm(a, b, sizes), lhs, rhs)
+        return out, vjp(out)
+
+    hlo = _compile(fwd_bwd, chip, ((4096 * 6, 2048), jnp.bfloat16),
+                   ((8, 2048, 1408), jnp.bfloat16), ((8,), jnp.int32))
+    assert "_expert_gmm" in hlo and "_expert_tgmm" in hlo
 
 
 def test_fused_xent_at_vocab_50304(chip):
